@@ -1,8 +1,8 @@
 """Mesh-plan sweep: ordering/fit time per mesh shape vs the 1-device oracle.
 
-Sweeps the mesh shapes 1x1, 2x2, 4x1, 8x1 over 8 forced host devices
-(subprocess, so the parent process keeps its single default device) and
-times, per shape:
+Sweeps the mesh shapes 1x1, 2x2, 4x1, 8x1 over 8 forced host CPU
+devices (a ``JAX_PLATFORMS=cpu`` subprocess, so the parent keeps its
+default device and any accelerator to itself) and times, per shape:
 
   * the sharded ordering (``make_sharded_causal_order`` — the 96% hot
     path) and its per-step cost,
@@ -99,6 +99,9 @@ def run(quick: bool = True):
     root = os.path.dirname(here)
     env["PYTHONPATH"] = os.path.join(root, "src")
     env.pop("XLA_FLAGS", None)
+    # The child's virtual devices are CPU devices: it must never contend
+    # with the parent for an accelerator, which one process holds.
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(m), str(d), str(chunk)],
         capture_output=True, text=True, env=env, cwd=root, timeout=3600,

@@ -1,7 +1,8 @@
 """Paper Fig. 2 analogue: runtime scaling of the causal-ordering
 sub-procedure, sequential (numpy pair loop) vs parallel (vectorized jnp /
-Pallas-interpret), over a (samples x dims) grid; plus the fraction of
-total DirectLiNGAM runtime spent in ordering.
+Pallas, interpreted off-chip: ``pallas_interpret`` says which), over a
+(samples x dims) grid; plus the fraction of total DirectLiNGAM runtime
+spent in ordering.
 
 On this CPU container the "parallel" rows measure the vectorized
 single-core implementations (the TPU speed-up story is the §Roofline
@@ -20,6 +21,7 @@ import numpy as np
 from repro.baselines import sequential_lingam as seq
 from repro.core.ordering import causal_order
 from repro.data.simulate import simulate_lingam
+from repro.kernels.tune.registry import resolve_interpret
 
 
 def _time(fn, *args, reps=1):
@@ -49,9 +51,7 @@ def run(quick: bool = True):
             lambda: causal_order(jax.numpy.asarray(x), backend="blocked")
         )
         t_pal = _time(
-            lambda: causal_order(
-                jax.numpy.asarray(x), backend="pallas", interpret=True
-            )
+            lambda: causal_order(jax.numpy.asarray(x), backend="pallas")
         )
         # ordering fraction of the full sequential fit (paper: 96%)
         t0 = time.perf_counter()
@@ -66,7 +66,8 @@ def run(quick: bool = True):
             "m": m, "d": d,
             "sequential_s": t_seq,
             "parallel_blocked_s": t_par,
-            "parallel_pallas_interpret_s": t_pal,
+            "parallel_pallas_s": t_pal,
+            "pallas_interpret": resolve_interpret(None),
             "speedup_blocked": t_seq / t_par,
             "ordering_fraction": frac,
         })

@@ -70,8 +70,10 @@ def main() -> None:
                          "always land as repo-root BENCH_*.json)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache  # noqa: E402,PLC0415
     from repro.obs import profile as obs_profile  # noqa: E402,PLC0415
 
+    enable_compile_cache()
     if args.profile:
         obs_profile.enable()
 
@@ -178,6 +180,11 @@ def main() -> None:
         with open(args.out, "w") as f:
             json.dump(results, f, indent=1, default=default)
         print(f"wrote {args.out}")
+
+    failed = [n for n, r in results.items()
+              if isinstance(r, dict) and "error" in r]
+    if failed:
+        sys.exit(f"benches failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

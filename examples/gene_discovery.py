@@ -13,10 +13,13 @@ from benchmarks.bench_gene import run
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale d=961 (slow on CPU)")
     args = ap.parse_args()
+    enable_compile_cache()
     results = run(quick=not args.full)
     print("\nSummary (lower is better):")
     for method, r in results.items():

@@ -32,6 +32,7 @@ from repro.core import DirectLiNGAM, VarLiNGAM, api, batched
 from repro.core.bootstrap import bootstrap_lingam
 from repro.data.simulate import simulate_do, simulate_lingam, simulate_var_stocks
 from repro.infer import effects, intervene, rca
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -51,7 +52,7 @@ def main():
           f"correct={np.sum(est & true)}")
 
     print("\n=== Pallas kernel backend (interpret mode on CPU) ===")
-    model_k = DirectLiNGAM(backend="pallas", interpret=True).fit(gt.data)
+    model_k = DirectLiNGAM(backend="pallas").fit(gt.data)
     print("pallas order :", model_k.causal_order_)
     print("orders agree :", np.array_equal(model.causal_order_,
                                            model_k.causal_order_))
@@ -238,6 +239,7 @@ if __name__ == "__main__":
                     help="directory for --profile artifacts "
                          "(host trace, device trace, cost snapshot)")
     args = ap.parse_args()
+    enable_compile_cache()
     main()
     if args.telemetry:
         telemetry_demo(out_dir=args.telemetry_out)

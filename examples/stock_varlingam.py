@@ -156,6 +156,9 @@ def main():
         help="regime-change demo: drift alerts + adaptive refit cadence",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.drift:
         run_drift(args.full)
         return

@@ -1,9 +1,8 @@
-"""The paper's own workload configs (per the brief: one config per
-assigned architecture *plus the paper's own*).
+"""The paper's deployment sizes: (name, m samples, d variables).
 
-Each entry is a (name, m samples, d variables) causal-discovery cell that
-runs through the same dry-run / roofline / hillclimb machinery as the LM
-architectures via ``repro.core.sharded.make_sharded_causal_order``.
+``chip_smoke.py`` runs DirectLiNGAM at ``lingam-gene-964`` and the
+serving engine and VarLiNGAM at ``varlingam-stocks-487``;
+``benchmarks/bench_bootstrap.py`` reads the table as well.
 """
 
 from __future__ import annotations

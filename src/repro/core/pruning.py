@@ -23,6 +23,20 @@ import jax.numpy as jnp
 
 EPS = 1e-9
 
+#: Floats one batch of row solves may hold per copy of its systems. Each
+#: row builds its own masked (d, d) system, so solving all d rows at
+#: once holds d^3 floats per copy (about 13 GiB of temporaries at
+#: d = 964); rows are solved in batches of at most this many floats
+#: instead — one batch, i.e. a plain vmap, whenever d^3 fits.
+_SOLVE_BATCH_FLOATS = 1 << 24
+
+
+def _map_rows(fn, *rows):
+    """``vmap(fn)`` over the leading row axis, in memory-bounded batches."""
+    d = rows[0].shape[-1]
+    batch = max(1, _SOLVE_BATCH_FLOATS // (d * d))
+    return jax.lax.map(lambda r: fn(*r), rows, batch_size=batch)
+
 
 def pred_mask(order):
     """(d, d) bool: mask[i, j] = True iff j precedes i in the causal order."""
@@ -52,7 +66,7 @@ def ols_rows(cov, mask_rows, cov_rows):
         b = jnp.where(mask_i, cov_xi, 0.0)
         return jnp.linalg.solve(a, b)
 
-    return jax.vmap(solve_one)(mask_rows, cov_rows)
+    return _map_rows(solve_one, mask_rows, cov_rows)
 
 
 def ols_from_cov(cov, order):
@@ -116,7 +130,7 @@ def lasso_rows(cov, mask_rows, cov_rows, w_rows, lam, lip, n_steps):
         )
         return b
 
-    return jax.vmap(fista)(mask_rows, cov_rows, w_rows)
+    return _map_rows(fista, mask_rows, cov_rows, w_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("n_steps",))

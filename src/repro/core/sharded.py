@@ -52,7 +52,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
@@ -337,12 +336,12 @@ def _build_sharded_fit(m: int, d: int, config: FitConfig):
             )
         return order, b, resid_var
 
-    fn = shard_map(
+    fn = jax.shard_map(
         full_fit,
         mesh=mesh,
         in_specs=P(part.sample_axes, None),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), m_pad, d_pad
 
@@ -412,12 +411,12 @@ def make_sharded_causal_order(
         )
         return ordering.masked_order_impl(x_local, reducer, d=d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         ordered,
         mesh=mesh,
         in_specs=P(sample_axes, None),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), m_pad, d_pad
 

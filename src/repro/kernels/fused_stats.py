@@ -21,8 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pairwise_stats import _accumulate, _fit_block
-from .tune.registry import dispatch
+from .pairwise_stats import _accumulate
 
 EPS = 1e-12
 LOG2 = 0.6931471805599453
@@ -40,8 +39,8 @@ def _fused_kernel(x_i_ref, x_j_ref, mu_i_ref, mu_j_ref, rs_i_ref, rs_j_ref,
     # Standardize raw tiles in VMEM (affine per variable row).
     xi = x_i_ref[...].astype(jnp.float32)  # (BI, BM) raw
     xj = x_j_ref[...].astype(jnp.float32)  # (BJ, BM) raw
-    xi = (xi - mu_i_ref[...][:, None]) * rs_i_ref[...][:, None]
-    xj = (xj - mu_j_ref[...][:, None]) * rs_j_ref[...][:, None]
+    xi = (xi - mu_i_ref[...]) * rs_i_ref[...]  # (BI, 1) constants
+    xj = (xj - mu_j_ref[...]) * rs_j_ref[...]  # (BJ, 1) constants
     c = c_ref[...].astype(jnp.float32)     # (BI, BJ)
 
     sample_ids = k * bm + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bm), 2)
@@ -76,28 +75,21 @@ def fused_moment_sums(
     c_rows,
     *,
     m_total: int,
-    bi: int = None,
-    bj: int = None,
-    bm: int = None,
+    bi: int,
+    bj: int,
+    bm: int,
     interpret: bool = False,
 ):
     """Moment *sums* for a row tile against all variables, from raw X.
 
     x_raw_rows: (tile, m_pad) raw (fp32 or bf16 — §Perf C3);
     x_raw_all:  (d_pad, m_pad); mu/rstd: per-variable standardization
-    constants; c_rows: (tile, d_pad) correlation rows.
-    Returns (S1, S2): (tile, d_pad) fp32 sums over valid samples.
-    Block shapes default to the dispatcher's plan, clamped to divisors.
+    constants as (tile, 1) / (d_pad, 1) columns; c_rows: (tile, d_pad)
+    correlation rows. Returns (S1, S2): (tile, d_pad) fp32 sums over
+    valid samples. The blocks must tile the padded shapes exactly.
     """
     tile, m_pad = x_raw_rows.shape
     d_pad = x_raw_all.shape[0]
-    if bi is None or bj is None or bm is None:
-        plan = dispatch(
-            "fused_moment_sums", (tile, d_pad, m_pad), backend="pallas"
-        )
-        bi = bi or _fit_block(tile, plan.bi)
-        bj = bj or _fit_block(d_pad, plan.bj)
-        bm = bm or (plan.bm if m_pad % plan.bm == 0 else m_pad)
     assert tile % bi == 0 and d_pad % bj == 0 and m_pad % bm == 0
     grid = (tile // bi, d_pad // bj, m_pad // bm)
     kernel = functools.partial(_fused_kernel, bm=bm, m_total=m_total)
@@ -108,10 +100,10 @@ def fused_moment_sums(
     in_specs = [
         pl.BlockSpec((bi, bm), lambda i, j, k: (i, k)),   # raw rows
         pl.BlockSpec((bj, bm), lambda i, j, k: (j, k)),   # raw all
-        pl.BlockSpec((bi,), lambda i, j, k: (i,)),        # mu rows
-        pl.BlockSpec((bj,), lambda i, j, k: (j,)),        # mu all
-        pl.BlockSpec((bi,), lambda i, j, k: (i,)),        # rstd rows
-        pl.BlockSpec((bj,), lambda i, j, k: (j,)),        # rstd all
+        pl.BlockSpec((bi, 1), lambda i, j, k: (i, 0)),    # mu rows
+        pl.BlockSpec((bj, 1), lambda i, j, k: (j, 0)),    # mu all
+        pl.BlockSpec((bi, 1), lambda i, j, k: (i, 0)),    # rstd rows
+        pl.BlockSpec((bj, 1), lambda i, j, k: (j, 0)),    # rstd all
         pl.BlockSpec((bi, bj), lambda i, j, k: (i, j)),   # corr rows
     ]
     out_specs = [

@@ -116,8 +116,8 @@ def pairwise_moments(
         return pairwise_moments_blocked(x_std, c, block=block or plan.block)
     if plan.backend == "pallas":
         interpret = tune.resolve_interpret(interpret)
-        bi, bj, bm = plan.bi, plan.bj, plan.bm
-        d_pad = _round_up(d, max(bi, bj))
+        bi, bm = plan.bi, plan.bm
+        d_pad, bj = tune.padded_extent(d, bi, plan.bj)
         m_pad = _round_up(m, bm)
         xt = jnp.pad(
             x_std.T.astype(jnp.float32), ((0, d_pad - d), (0, m_pad - m))
@@ -175,34 +175,24 @@ def pairwise_moment_sums_rows(
         )
     if plan.backend == "pallas":
         interpret = tune.resolve_interpret(interpret)
-        bi = plan.bi if plan.bi and tile % plan.bi == 0 else (
-            8 if tile % 8 == 0 else 1
-        )
-        bj = plan.bj if plan.bj and d % plan.bj == 0 else None
-        bm = plan.bm if plan.bm else (
-            chunk if m_local % chunk == 0 else m_local
-        )
-        d_pad = d if bj else _round_up(d, 8 if d >= 8 else 1)
+        bi, bm = plan.bi, plan.bm
+        tile_pad = _round_up(tile, bi)
+        # Pad variables and samples to block multiples: padded rows and
+        # columns are sliced back off below, padded samples are masked
+        # via m_total (and contribute exact zeros to the sub-sums). The
+        # padded rows also hold a tile_pad slice from any valid
+        # row_start (<= d - tile), so dynamic_slice never clamps it.
+        d_pad, bj = tune.padded_extent(d + tile_pad - tile, bi, plan.bj)
         m_pad = _round_up(m_local, bm)
-        xt_all = x_std.T  # (d, m_local)
-        c_full = c
-        if d_pad != d or m_pad != m_local:
-            # Pad variables/samples to block multiples: padded columns
-            # are sliced back off below, padded samples are masked via
-            # m_total (and contribute exact zeros to the sub-sums).
-            xt_all = jnp.pad(
-                xt_all, ((0, d_pad - d), (0, m_pad - m_local))
-            )
-            c_full = jnp.pad(c, ((0, d_pad - d), (0, d_pad - d)))
-        if bj is None:
-            bj = 8 if d_pad % 8 == 0 else 1
-        xt_rows = jax.lax.dynamic_slice_in_dim(xt_all, row_start, tile, 0)
-        c_rows = jax.lax.dynamic_slice_in_dim(c_full, row_start, tile, 0)
+        xt_all = jnp.pad(x_std.T, ((0, d_pad - d), (0, m_pad - m_local)))
+        c_full = jnp.pad(c, ((0, d_pad - d), (0, d_pad - d)))
+        xt_rows = jax.lax.dynamic_slice_in_dim(xt_all, row_start, tile_pad, 0)
+        c_rows = jax.lax.dynamic_slice_in_dim(c_full, row_start, tile_pad, 0)
         s1, s2 = pairwise_stats.pairwise_moment_sums_rows(
             xt_rows, xt_all, c_rows, m_total=m_local,
             bi=bi, bj=bj, bm=bm, interpret=interpret,
         )
-        return s1[:, :d], s2[:, :d]
+        return s1[:tile, :d], s2[:tile, :d]
     if plan.backend != "blocked":
         raise ValueError(f"unknown backend: {plan.backend}")
     xt = x_std.T  # (d, m_local)
@@ -366,15 +356,17 @@ def fused_moment_rows(
             "pallas", mode=tune_mode,
         )
     interpret = tune.resolve_interpret(interpret)
-    bi, bj, bm = plan.bi, plan.bj, plan.bm
+    bi, bm = plan.bi, plan.bm
     tile_pad = _round_up(tile, bi)
     # The row slice must fit inside the padded variable extent even when
     # the tile straddles the end of the real rows.
-    d_pad = _round_up(max(d, row_start + tile_pad), bj)
+    d_pad, bj = tune.padded_extent(max(d, row_start + tile_pad), bi, plan.bj)
     m_pad = _round_up(m, bm)
     xt = jnp.pad(x_raw.T, ((0, d_pad - d), (0, m_pad - m)))
-    mu_pad = jnp.pad(mu.astype(jnp.float32), (0, d_pad - d))
-    rstd_pad = jnp.pad(rstd.astype(jnp.float32), (0, d_pad - d))
+    # Per-variable constants as (d_pad, 1) columns: 2-D blocks that
+    # Mosaic lays out like the data rows they scale.
+    mu_pad = jnp.pad(mu.astype(jnp.float32), (0, d_pad - d))[:, None]
+    rstd_pad = jnp.pad(rstd.astype(jnp.float32), (0, d_pad - d))[:, None]
     c_pad = jnp.pad(
         c.astype(jnp.float32), ((0, d_pad - d), (0, d_pad - d))
     )

@@ -28,9 +28,10 @@ Grid: (d/BI, d/BJ, ceil(m/BM)). All block dims are padded by the wrapper
 (ops.py) to hardware-friendly multiples; padding samples are masked here.
 
 Block shapes come from the autotuning dispatcher
-(:mod:`repro.kernels.tune`): ``bi``/``bj``/``bm`` default to None and are
-resolved via ``dispatch`` against the (already padded) input shapes. The
-sample axis accumulates in fixed ``ACCUM_CHUNK``-wide sub-chunks, so any
+(:mod:`repro.kernels.tune`) through the ops wrappers, which pad every
+extent to them; each block's last dimension is a multiple of 128 or the
+whole padded extent, as the TPU requires. The sample axis accumulates
+in fixed ``ACCUM_CHUNK``-wide sub-chunks, so any
 ``bm`` that is a multiple of it produces a bit-identical reduction order
 — tuned and heuristic plans differ only in speed, never in bits (the
 zero-masked padded tail contributes exact ``+0.0`` terms).
@@ -44,19 +45,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tune.registry import ACCUM_CHUNK, dispatch
+from .tune.registry import ACCUM_CHUNK
 
 EPS = 1e-12
 LOG2 = 0.6931471805599453
-
-
-def _fit_block(n: int, preferred: int) -> int:
-    """Largest of (preferred, 8, 1) that divides the padded extent — a
-    tuned plan from a wider bucket must still tile this array exactly."""
-    for b in (preferred, 8, 1):
-        if b and n % b == 0:
-            return b
-    return 1
 
 
 def _accumulate(m1_ref, m2_ref, logcosh, uexp, bm):
@@ -116,9 +108,9 @@ def pairwise_moment_sums_rows(
     c_rows,
     *,
     m_total: int,
-    bi: int = None,
-    bj: int = None,
-    bm: int = None,
+    bi: int,
+    bj: int,
+    bm: int,
     interpret: bool = False,
 ):
     """Row-tile variant for the sharded (shard_map) path: moment *sums*
@@ -126,19 +118,11 @@ def pairwise_moment_sums_rows(
 
     x_rows: (tile, m_pad); x_all: (d_pad, m_pad); c_rows: (tile, d_pad).
     Returns (S1, S2) of shape (tile, d_pad) — caller psums over sample
-    shards and divides by the global sample count. Block shapes default
-    to the dispatcher's plan for the (already padded) input shapes.
+    shards and divides by the global sample count. The blocks must tile
+    the (already padded) input shapes exactly.
     """
     tile, m_pad = x_rows.shape
     d_pad = x_all.shape[0]
-    if bi is None or bj is None or bm is None:
-        plan = dispatch(
-            "pairwise_moment_sums_rows", (tile, d_pad, m_pad),
-            backend="pallas",
-        )
-        bi = bi or _fit_block(tile, plan.bi)
-        bj = bj or _fit_block(d_pad, plan.bj)
-        bm = bm or (plan.bm if m_pad % plan.bm == 0 else m_pad)
     assert tile % bi == 0 and d_pad % bj == 0 and m_pad % bm == 0, (
         tile, d_pad, m_pad, bi, bj, bm)
     grid = (tile // bi, d_pad // bj, m_pad // bm)
@@ -174,9 +158,9 @@ def pairwise_moments_pallas(
     c,
     *,
     m_total: int,
-    bi: int = None,
-    bj: int = None,
-    bm: int = None,
+    bi: int,
+    bj: int,
+    bm: int,
     interpret: bool = False,
 ):
     """Pairwise residual moments via the Pallas kernel.
@@ -189,18 +173,8 @@ def pairwise_moments_pallas(
       m_total: number of valid samples (<= m_pad).
     Returns:
       (M1, M2): (d_pad, d_pad) fp32 moment matrices (means over samples).
-
-    Block shapes default to the dispatcher's plan for the (padded)
-    input shapes, clamped to exact divisors.
     """
     d_pad, m_pad = x_t.shape
-    if bi is None or bj is None or bm is None:
-        plan = dispatch(
-            "pairwise_moments", (m_pad, d_pad), backend="pallas"
-        )
-        bi = bi or _fit_block(d_pad, plan.bi)
-        bj = bj or _fit_block(d_pad, plan.bj)
-        bm = bm or (plan.bm if m_pad % plan.bm == 0 else m_pad)
     assert d_pad % bi == 0 and d_pad % bj == 0, (d_pad, bi, bj)
     assert m_pad % bm == 0, (m_pad, bm)
     grid = (d_pad // bi, d_pad // bj, m_pad // bm)

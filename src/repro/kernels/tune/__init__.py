@@ -19,11 +19,10 @@ dispatched subsystem:
     benchmarks candidates per ``(device_kind, op, shape-bucket,
     dtype)`` and emits a :class:`~repro.kernels.tune.autotune.TunePlan`.
   * :mod:`cache <repro.kernels.tune.cache>` — the persistent JSON
-    tuning table (repo-committed ``default_plans.json`` + user-local
-    overlay at ``$REPRO_TUNE_CACHE`` or
-    ``~/.cache/repro/tune_plans.json``) with shape bucketing and
-    versioned keys, so serving and streaming sessions hit tuned plans
-    without a first-request search.
+    tuning table (repo-committed ``default_plans.json`` + an overlay
+    file, read only when ``$REPRO_TUNE_CACHE`` names one) with shape
+    bucketing and versioned keys, so serving and streaming sessions hit
+    tuned plans without a first-request search.
 
 Modes (``FitConfig.tune`` / ``dispatch(mode=...)``): ``"off"`` is the
 deterministic offline fallback (pure heuristic, no filesystem),

@@ -29,7 +29,7 @@ from . import cache as tune_cache
 from . import registry
 
 _BI_GRID = (8, 16)
-_BJ_GRID = (8, 16, 128)
+_BJ_GRID = (128, 256)  # lane multiples; at most 128 columns take one block
 _BM_GRID = (128, 256, 512, 1024, 2048)
 
 
@@ -128,6 +128,11 @@ def candidate_plans(
         plans.append(p)
 
     tunable = set(cons.tunable)
+    d_axis = shape[1]  # d for every pair op: (m, d) or (tile, d, m)
+    bj_grid = (
+        _BJ_GRID if d_axis > registry._LANE
+        else (registry.lane_block(d_axis),)
+    )
     if tunable >= {"bi", "bj", "bm"}:
         m_axis = shape[0] if len(shape) == 2 else shape[2]
         bi_grid = _BI_GRID[:1] if quick else _BI_GRID
@@ -138,14 +143,14 @@ def candidate_plans(
             and bm <= registry._round_up(m_axis, cons.accum_chunk)
         ]
         for bi in bi_grid:
-            for bj in _BJ_GRID:
+            for bj in bj_grid:
                 for bm in bm_grid:
                     if registry.vmem_bytes(bi, bj, bm) > cons.vmem_budget:
                         continue
                     add(bi=bi, bj=bj, bm=bm)
     elif tunable == {"bi", "bj"}:
         for bi in (_BI_GRID[:1] if quick else _BI_GRID):
-            for bj in _BJ_GRID:
+            for bj in bj_grid:
                 if registry.vmem_bytes(bi, bj, heur.bm) > cons.vmem_budget:
                     continue
                 add(bi=bi, bj=bj)

@@ -16,9 +16,11 @@ Two layers merge at load time:
     to the repo. The shipped file carries no entries (every platform
     falls back to the deterministic heuristic until tuned); CI's tune
     job and ``benchmarks/bench_tune.py`` show the round trip.
-  * **overlay** — a user-local JSON (``$REPRO_TUNE_CACHE`` or
-    ``~/.cache/repro/tune_plans.json``); ``record()`` writes here, and
-    overlay entries shadow defaults with the same key.
+  * **overlay** — a JSON file named by ``$REPRO_TUNE_CACHE``, read
+    only when that variable is set, so nothing outside the checkout
+    shapes what compiles by default; ``record()`` writes here (or keeps
+    the entry in memory when no overlay is named), and overlay entries
+    shadow defaults with the same key.
 
 ``TuneTable(offline=True)`` never touches the filesystem and never
 returns a tuned entry — ``dispatch`` then degrades to the heuristic
@@ -41,14 +43,9 @@ _lock = threading.Lock()
 _table: Optional["TuneTable"] = None
 
 
-def overlay_path() -> str:
-    """User-local overlay location (env override > XDG-ish default)."""
-    env = os.environ.get(_OVERLAY_ENV)
-    if env:
-        return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "tune_plans.json"
-    )
+def overlay_path() -> Optional[str]:
+    """The overlay file ``$REPRO_TUNE_CACHE`` names, or None."""
+    return os.environ.get(_OVERLAY_ENV) or None
 
 
 def bucket_pow2(n: int, lo: int = 8) -> int:
@@ -117,7 +114,8 @@ class TuneTable:
         self._overlay: Dict[str, dict] = {}
         if not offline:
             self._defaults = _load_json(self.default_path)
-            self._overlay = _load_json(self.overlay_path)
+            if self.overlay_path:
+                self._overlay = _load_json(self.overlay_path)
 
     def lookup(self, key: str) -> Optional[dict]:
         """Overlay entry if present, else the committed default."""
@@ -126,11 +124,12 @@ class TuneTable:
         return self._overlay.get(key) or self._defaults.get(key)
 
     def record(self, key: str, entry: dict, *, persist: bool = True) -> None:
-        """Install a measured plan (overlay layer; optionally on disk)."""
+        """Install a measured plan (overlay layer; on disk as well when
+        ``persist`` and an overlay file is named)."""
         if self.offline:
             raise RuntimeError("cannot record into an offline TuneTable")
         self._overlay[key] = dict(entry)
-        if persist:
+        if persist and self.overlay_path:
             self.save_overlay()
 
     def save_overlay(self) -> None:

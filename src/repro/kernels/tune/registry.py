@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Dict, Optional, Tuple
 
 from repro.obs import metrics as obs_metrics
@@ -71,10 +72,7 @@ def _trace_state_clean() -> bool:
     bench harness, a direct ops call)."""
     import jax
 
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # pragma: no cover - future jax versions
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 def vmem_bytes(bi: int, bj: int, bm: int) -> int:
@@ -82,6 +80,30 @@ def vmem_bytes(bi: int, bj: int, bm: int) -> int:
     streamed input blocks plus the two (BI, BJ, BM) moment
     intermediates (residual/nonlinearity tensors)."""
     return 4 * (bi * bm + bj * bm + 2 * bi * bj * bm)
+
+
+def lane_block(d: int) -> int:
+    """Column block for ``d`` pair columns: one 128-lane tile when the
+    columns span more than one, else every column (padded to a sublane
+    multiple) in a single block."""
+    return _LANE if d > _LANE else _round_up(max(d, 1), _SUBLANE)
+
+
+def padded_extent(d: int, bi: int, bj: int) -> Tuple[int, int]:
+    """``(d_pad, bj)``: the padded pair-column extent and the column
+    block the TPU accepts for it.
+
+    Mosaic takes a block only when its last dimension is a multiple of
+    the 128-lane tile or the whole array extent (and its second-to-last
+    a multiple of 8 or the whole extent). A lane-multiple ``bj`` tiles
+    the columns padded up to it and to the row block ``bi``; any other
+    ``bj`` — the plans for at most 128 columns — becomes one block
+    spanning all columns, padded to a multiple of ``bi``.
+    """
+    if bj % _LANE == 0:
+        return _round_up(d, math.lcm(bi, bj)), bj
+    d_pad = _round_up(d, bi)
+    return d_pad, d_pad
 
 
 @functools.lru_cache(maxsize=1)
@@ -201,15 +223,13 @@ def get_variant(op: str, backend: str) -> KernelVariant:
 
 
 def heuristic_pair_blocks(d: int, m: int) -> Tuple[int, int, int]:
-    """MXU/VPU-aligned pair-tile block shapes, VMEM-bounded.
+    """Lane-legal pair-tile block shapes, VMEM-bounded.
 
     The (BI, BJ, BM) intermediate is the VMEM working set (see
-    :func:`vmem_bytes`); these defaults are the legacy
-    ``ops._pick_blocks`` heuristic with its duplicate ``d >= 8`` /
-    ``else`` branches collapsed (both returned 8 — tiny d is padded up
-    to one sublane tile anyway).
+    :func:`vmem_bytes`); ``bj`` is one 128-lane tile, or every column
+    when there are at most 128 (:func:`lane_block`).
     """
-    bi, bj = (8, 128) if d >= 128 else (8, 8)
+    bi, bj = _SUBLANE, lane_block(d)
     if m >= 4096:
         bm = 2048
     elif m >= 512:
@@ -239,12 +259,13 @@ def _pair_blocked_heuristic(shape, chunk=None) -> Plan:
 
 def _rows_pallas_heuristic(shape, chunk=None) -> Plan:
     tile, d, m = shape
-    bi = _SUBLANE if tile % _SUBLANE == 0 else 1
-    bj = _LANE if d % _LANE == 0 else (_SUBLANE if d % _SUBLANE == 0 else 1)
-    bm = chunk if chunk and m % chunk == 0 else m
+    # The sample block is the caller's chunk when that is lane-aligned
+    # and tiles m, else the whole sample extent (the only other block
+    # the TPU accepts).
+    bm = chunk if chunk and chunk % _LANE == 0 and m % chunk == 0 else m
     return Plan(
         op="pairwise_moment_sums_rows", variant="pallas-row-tile",
-        backend="pallas", bi=bi, bj=bj, bm=bm,
+        backend="pallas", bi=_SUBLANE, bj=lane_block(d), bm=bm,
     )
 
 
@@ -273,23 +294,26 @@ def _chunked_heuristic(backend, name):
 
 def _fused_pallas_heuristic(shape, chunk=None) -> Plan:
     tile, d, m = shape
-    bi = _SUBLANE
-    bj = _LANE if d >= _LANE else _SUBLANE
-    bm = 512 if m >= 512 else 256
     return Plan(
         op="fused_moment_sums", variant="pallas-fused",
-        backend="pallas", bi=bi, bj=bj, bm=bm,
+        backend="pallas", bi=_SUBLANE, bj=lane_block(d),
+        bm=512 if m >= 512 else 256,
     )
 
 
 def _validate_pallas(plan: Plan, shape, chunk=None) -> bool:
-    """A tuned Pallas plan is admissible for this shape when its blocks
-    are aligned, bit-stable (bm a multiple of the accumulation chunk)
-    and within the chunk memory bound when one applies. Divisibility is
-    *not* required — the ops wrappers pad to the plan's blocks."""
+    """A tuned Pallas plan is admissible for this shape when the TPU
+    accepts its blocks (rows a sublane multiple; columns a lane
+    multiple, or one block over at most 128 columns — see
+    :func:`padded_extent`), it is bit-stable (bm a multiple of the
+    accumulation chunk) and within the chunk memory bound when one
+    applies. Divisibility is *not* required — the ops wrappers pad to
+    the plan's blocks. ``shape[1]`` is d for every pair op."""
     if plan.bi < 1 or plan.bj < 1 or plan.bm < 1:
         return False
-    if plan.bi % _SUBLANE or plan.bj % _SUBLANE:
+    if plan.bi % _SUBLANE:
+        return False
+    if plan.bj % _LANE and shape[1] > _LANE:
         return False
     if plan.bm % ACCUM_CHUNK:
         return False

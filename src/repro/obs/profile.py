@@ -76,12 +76,9 @@ def _trace_clean() -> bool:
     """True when no jax trace is active. Cost capture must never run
     mid-trace: lowering there would stage host work into someone else's
     program; inside a trace :func:`call` degrades to a plain call."""
-    try:
-        import jax
+    import jax
 
-        return jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax absent/ancient
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 # ---------------------------------------------------------------------------
@@ -107,9 +104,9 @@ class DevicePeaks:
 
 
 #: Substring-matched (against ``device_kind.lower()``) peak entries,
-#: first match wins. The cpu entry is a deliberately round placeholder
-#: for a ~2-core container — override with ``REPRO_PEAKS`` for real
-#: host baselines.
+#: first match wins; a device kind that matches none is an error. The
+#: cpu entry is a deliberately round placeholder for a ~2-core
+#: container — override with ``REPRO_PEAKS`` for real host baselines.
 PEAKS_TABLE: Tuple[Tuple[str, DevicePeaks], ...] = (
     ("v5 lite", DevicePeaks("tpu-v5e", 197e12, 819e9, 50e9)),
     ("v5e", DevicePeaks("tpu-v5e", 197e12, 819e9, 50e9)),
@@ -122,29 +119,25 @@ PEAKS_TABLE: Tuple[Tuple[str, DevicePeaks], ...] = (
     ("cpu", DevicePeaks("cpu-generic", 100e9, 20e9, 10e9)),
 )
 
-_FALLBACK_PEAKS = DevicePeaks("unknown", 100e9, 20e9, 10e9)
-
 
 def device_peaks(kind: Optional[str] = None) -> DevicePeaks:
     """Roofline ceilings for ``kind`` (default: the process's device).
 
-    ``REPRO_PEAKS`` overrides individual fields on top of the detected
-    entry — ``REPRO_PEAKS="flops=3.2e12,hbm=80e9"`` calibrates a real
-    host without code changes (keys: name/flops/hbm/ici).
+    Raises ``KeyError`` for a device kind missing from
+    :data:`PEAKS_TABLE`: a roofline against invented peaks would be
+    read as a measurement. ``REPRO_PEAKS`` overrides individual fields
+    on top of the detected entry — ``REPRO_PEAKS="flops=3.2e12,hbm=80e9"``
+    calibrates a real host without code changes (keys:
+    name/flops/hbm/ici).
     """
     if kind is None:
-        try:
-            import jax
+        import jax
 
-            kind = jax.devices()[0].device_kind
-        except Exception:  # pragma: no cover - jax must not be a hard dep
-            kind = "unknown"
+        kind = jax.devices()[0].device_kind
     low = str(kind).lower()
-    base = _FALLBACK_PEAKS
-    for token, peaks in PEAKS_TABLE:
-        if token in low:
-            base = peaks
-            break
+    base = next((p for token, p in PEAKS_TABLE if token in low), None)
+    if base is None:
+        raise KeyError(f"no roofline peaks for device kind {kind!r}")
     env = os.environ.get(_PEAKS_ENV, "").strip()
     if not env:
         return base

@@ -83,12 +83,9 @@ def enabled() -> bool:
 
 def _in_jax_trace() -> bool:
     """True while jax is building a trace (span executes at trace time)."""
-    try:
-        import jax
+    import jax
 
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax absent/ancient
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class Span:

@@ -149,8 +149,9 @@ class CausalDiscoveryEngine:
 
         With ``tune_mode="auto"`` (or ``FitConfig(tune="auto")``) the
         block-shape search runs *now*, per shape bucket, and persists to
-        the user-local tuning overlay — so neither one-shot requests nor
-        streaming refits ever pay a first-request search. Returns the
+        the tuning overlay (``$REPRO_TUNE_CACHE``) — so neither one-shot
+        requests nor streaming refits ever pay a first-request search.
+        Returns the
         resolved plans keyed by their tuning-table keys.
         """
         from repro.kernels.tune import autotune as ktune_autotune

@@ -204,9 +204,10 @@ def test_fused_kernel_matches_oracle(dtype):
     xr = jnp.asarray(x).T  # (d, m) raw
     if dtype == "bfloat16":
         xr = xr.astype(jnp.bfloat16)
+    mu, rstd = mu[:, None], rstd[:, None]  # (d, 1) constant columns
     s1, s2 = fused_moment_sums(
         xr[:tile], xr, mu[:tile], mu, rstd[:tile], rstd, c[:tile],
-        m_total=m, bi=8, bj=8, bm=256, interpret=True,
+        m_total=m, bi=8, bj=16, bm=256, interpret=True,
     )
     atol = 2e-6 if dtype == np.float32 else 5e-2
     # mask the degenerate self-pair entries (i, i) of the (tile, d) slab
@@ -219,3 +220,76 @@ def test_fused_kernel_matches_oracle(dtype):
         np.asarray(m2r[:tile] * m * mask), np.asarray(s2 * mask),
         atol=atol * m, rtol=0,
     )
+
+
+# Every block a Pallas call gets from the dispatcher must tile the way
+# the TPU requires: last dimension a multiple of 128 or the whole array
+# extent, second-to-last a multiple of 8 or the whole extent. Checked on
+# the traced pallas_call at every d up to 130 (staged compaction shrinks
+# the width through all of them) and at the paper's widths.
+_WIDTHS = list(range(1, 131)) + [200, 487, 964, 1000]
+
+
+def _subjaxprs(params):
+    for v in params.values():
+        for item in v if isinstance(v, (list, tuple)) else (v,):
+            sub = getattr(item, "jaxpr", item)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _pallas_blocks(fn, *shapes):
+    """(block shape, array shape) of every pallas_call operand in fn."""
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                for bm in eqn.params["grid_mapping"].block_mappings:
+                    found.append((
+                        tuple(getattr(b, "block_size", b)
+                              for b in bm.block_shape),
+                        tuple(bm.array_aval.shape),
+                    ))
+            for sub in _subjaxprs(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*[
+        jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes
+    ]).jaxpr)
+    return found
+
+
+_BLOCK_CASES = {
+    "pair": lambda d: (
+        lambda x, c: ops.pairwise_moments(x, c, backend="pallas"),
+        (700, d), (d, d)),
+    "chunked": lambda d: (
+        lambda x, c: ops.pairwise_moments_chunked(
+            x, c, chunk=256, backend="pallas"),
+        (700, d), (d, d)),
+    "rows_half_tile": lambda d: (
+        lambda x, c: ops.pairwise_moment_sums_rows(
+            x, c, d - (d + 1) // 2, (d + 1) // 2, chunk=256,
+            backend="pallas"),
+        (512, d), (d, d)),
+    "fused": lambda d: (
+        lambda x, mu, rstd, c: ops.fused_moment_rows(
+            x, mu, rstd, c, 0, min(d, 8)),
+        (700, d), (d,), (d,), (d, d)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_pallas_blocks_tile_legally_at_every_width(case):
+    for d in _WIDTHS:
+        fn, *shapes = _BLOCK_CASES[case](d)
+        blocks = _pallas_blocks(fn, *shapes)
+        assert blocks, (case, d)
+        for block, array in blocks:
+            assert block[-1] % 128 == 0 or block[-1] == array[-1], (
+                case, d, block, array)
+            assert block[-2] % 8 == 0 or block[-2] == array[-2], (
+                case, d, block, array)

@@ -236,7 +236,9 @@ def test_device_peaks_resolution_and_override(monkeypatch):
     assert profile.device_peaks("NVIDIA H100 80GB HBM3").name == "gpu-h100"
     assert profile.device_peaks("TPU v4").name == "tpu-v4"
     assert profile.device_peaks("cpu").name == "cpu-generic"
-    assert profile.device_peaks("weird accelerator").name == "unknown"
+    assert profile.device_peaks("TPU v5 lite").name == "tpu-v5e"
+    with pytest.raises(KeyError, match="weird accelerator"):
+        profile.device_peaks("weird accelerator")
     # the process's own device resolves to *something* in the table
     assert profile.device_peaks().flops_per_s > 0
 

@@ -1,0 +1,95 @@
+"""The main path's Pallas kernels and the local fit compile for a TPU v5e.
+
+Compiled for one chip of a described ``v5e:2x2`` topology with
+``interpret=False``: nothing runs, but the TPU compiler refuses what
+interpret mode accepts — blocks off the (8, 128) tiling, kernels that
+overflow VMEM, programs that do not fit the chip's memory. The topology
+is described inside a fixture (the TPU library may be loaded by one
+process at a time), and the tests skip only there.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import api
+from repro.kernels import ops
+
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means no topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep it out of the cache.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+_PALLAS = dict(backend="pallas", interpret=False)
+
+# (case, kernel call, argument shapes): the widths the main path runs.
+_KERNELS = {
+    # gene-964: d_pad 1024, m_pad 65,536, blocks (8, 128, 2048).
+    "pair_gene964": (
+        lambda x, c: ops.pairwise_moments(x, c, **_PALLAS),
+        [(65_164, 964), (964, 964)],
+    ),
+    # 1m-100 width, one 2,048-sample block: one 104-column block.
+    "pair_d100": (
+        lambda x, c: ops.pairwise_moments(x, c, **_PALLAS),
+        [(2048, 100), (100, 100)],
+    ),
+    # One pair-axis row tile of the 2x2 mesh plan at gene-964.
+    "rows_d964_tile482": (
+        lambda x, c: ops.pairwise_moment_sums_rows(
+            x, c, 482, 482, chunk=512, **_PALLAS
+        ),
+        [(32_768, 964), (964, 964)],
+    ),
+    # Fused standardize + moments (Partition(fused_standardize=True)).
+    "fused_d964": (
+        lambda x, mu, rstd, c: ops.fused_moment_rows(
+            x, mu, rstd, c, 0, 8, interpret=False
+        ),
+        [(4096, 964), (964,), (964,), (964, 964)],
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, shapes = _KERNELS[case]
+    args = [_sds(one_chip, *s) for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_staged_local_fit_compiles_for_v5e(one_chip):
+    """The serving default (staged compaction, whose stage widths fall
+    below 128) at stocks-487, as one program that fits the chip."""
+    cfg = api.FitConfig(compaction="staged", **_PALLAS)
+    compiled = api._fit_local.lower(_sds(one_chip, 4000, 487), cfg).compile()
+    assert compiled.as_text().count("tpu_custom_call") > 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES

@@ -35,12 +35,13 @@ def _tmp_table(tmp_path):
 
 
 def test_heuristic_matches_legacy_pick_blocks():
-    """The collapsed heuristic reproduces the old ops._pick_blocks table
-    (duplicate d>=8/else branches folded — both returned 8)."""
+    """The heuristic keeps the old ops._pick_blocks rows and sample
+    blocks; below 128 columns bj is the whole padded column extent,
+    the only sub-lane block the TPU accepts."""
     legacy = {
         (300, 4): (8, 8, 256),
-        (300, 16): (8, 8, 256),
-        (600, 64): (8, 8, 512),
+        (300, 16): (8, 16, 256),
+        (600, 64): (8, 64, 512),
         (600, 128): (8, 128, 512),
         (5000, 200): (8, 128, 2048),
     }
@@ -173,7 +174,7 @@ def test_auto_mode_never_searches_inside_a_trace(tmp_path, monkeypatch):
         got = api.fit_fn(x, api.FitConfig(backend="blocked", tune="auto"))
         assert np.array_equal(np.asarray(ref.order), np.asarray(got.order))
         assert not os.path.exists(str(tmp_path / "auto.json"))
-        assert jax.core.trace_state_clean()
+        assert jax.core.trace_ctx.is_top_level()
     finally:
         cache.reset_table()
 
@@ -262,6 +263,12 @@ def test_offline_mode_is_heuristic_and_deterministic(tmp_path):
 def test_env_overlay_path(monkeypatch, tmp_path):
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path / "env.json"))
     assert cache.overlay_path() == str(tmp_path / "env.json")
+    # Unset, no overlay is read or written: only committed plans count.
+    monkeypatch.delenv("REPRO_TUNE_CACHE")
+    assert cache.overlay_path() is None
+    table = cache.TuneTable()
+    table.record("k", {"bi": 8})
+    assert table.lookup("k") == {"bi": 8} and table.overlay_path is None
 
 
 # ---------------------------------------------------------------------------
