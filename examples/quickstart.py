@@ -119,10 +119,9 @@ def telemetry_demo(out_dir=None):
     """Drive dispatch -> ordering -> pruning -> serve flush -> query
     with telemetry on; print the span tree + metrics + compile log.
 
-    ``out_dir`` additionally writes the run's artifacts to disk:
-    ``trace_events.json`` (Chrome/Perfetto trace-event format — open in
-    ``chrome://tracing`` or https://ui.perfetto.dev) and
-    ``metrics_snapshot.json``.
+    ``out_dir`` additionally writes ``metrics_snapshot.json``. For the
+    spans on a timeline with the device ops, run under
+    ``jax.profiler.trace`` (``--profile``).
     """
     import json
     import os
@@ -166,27 +165,25 @@ def telemetry_demo(out_dir=None):
 
     if out_dir is not None:
         os.makedirs(out_dir, exist_ok=True)
-        trace_path = obs.write_chrome_trace(
-            os.path.join(out_dir, "trace_events.json")
-        )
         metrics_path = os.path.join(out_dir, "metrics_snapshot.json")
         with open(metrics_path, "w") as f:
             json.dump(obs.metrics.snapshot(), f, indent=1, sort_keys=True)
-        print(f"wrote {trace_path} (open in chrome://tracing or "
-              f"ui.perfetto.dev) and {metrics_path}")
+        print(f"wrote {metrics_path}")
 
 
 def profile_demo(out_dir=None):
     """Profiled fit + stage attribution + correlated device trace.
 
-    ``out_dir`` receives ``trace_events.json`` (host spans, Chrome
-    trace-event format), a ``device_trace/`` directory (the
-    ``jax.profiler`` Perfetto/XPlane timeline with host span names
-    mirrored as TraceAnnotations), and ``profile_snapshot.json`` (the
-    captured cost records + device peaks).
+    ``out_dir`` receives a ``device_trace/`` directory (the
+    ``jax.profiler`` trace: host spans as annotations and device ops on
+    one clock, each op under its ``lingam.<stage>`` scope) and
+    ``profile_snapshot.json`` (the captured cost records + device
+    peaks).
     """
     import json
     import os
+
+    import jax
 
     from repro import obs
     from repro.analysis import report
@@ -199,7 +196,7 @@ def profile_demo(out_dir=None):
     print("\n=== Profiling: cost capture + roofline attribution ===")
     if out_dir is not None:
         os.makedirs(out_dir, exist_ok=True)
-        with profile.device_trace(os.path.join(out_dir, "device_trace")):
+        with jax.profiler.trace(os.path.join(out_dir, "device_trace")):
             payload = report.live_attribution(m=512, d=16, repeats=2)
     else:
         payload = report.live_attribution(m=512, d=16, repeats=2)
@@ -212,16 +209,13 @@ def profile_demo(out_dir=None):
               f"calls={rec.calls} best={rec.best_s * 1e3:.2f}ms")
 
     if out_dir is not None:
-        trace_path = obs.write_chrome_trace(
-            os.path.join(out_dir, "trace_events.json")
-        )
         snap_path = os.path.join(out_dir, "profile_snapshot.json")
         with open(snap_path, "w") as f:
             json.dump(profile.snapshot(), f, indent=1)
-        print(f"\nwrote {trace_path}, {snap_path}, and "
-              f"{os.path.join(out_dir, 'device_trace')}/ "
-              f"(open both traces in ui.perfetto.dev to correlate "
-              f"host spans with the device timeline)")
+        print(f"\nwrote {snap_path} and "
+              f"{os.path.join(out_dir, 'device_trace')}/ (open the trace "
+              f"in ui.perfetto.dev: host spans and device ops on one "
+              f"timeline)")
 
 
 if __name__ == "__main__":
@@ -231,13 +225,13 @@ if __name__ == "__main__":
                          "enabled and print span tree + metrics")
     ap.add_argument("--telemetry-out", type=str, default="telemetry_out",
                     help="directory for --telemetry artifacts "
-                         "(chrome trace + metrics snapshot)")
+                         "(metrics snapshot)")
     ap.add_argument("--profile", action="store_true",
                     help="run the profiled fit: stage-attribution table, "
                          "cost records, correlated host+device trace")
     ap.add_argument("--profile-out", type=str, default="profile_out",
                     help="directory for --profile artifacts "
-                         "(host trace, device trace, cost snapshot)")
+                         "(profiler trace, cost snapshot)")
     args = ap.parse_args()
     enable_compile_cache()
     main()

@@ -239,16 +239,18 @@ def finish_fit(x, order, config: FitConfig) -> FitResult:
     lowering differences of batched solves (exactly, at the parity
     cells the tests pin).
     """
-    b = pruning.estimate_adjacency(
-        x,
-        order,
-        method=config.prune_method,
-        threshold=config.prune_threshold,
-        **config.prune_kwargs_dict,
-    )
-    xc = x - jnp.mean(x, axis=0, keepdims=True)
-    resid = xc - xc @ b.T
-    resid_var = jnp.mean(resid * resid, axis=0)
+    with jax.named_scope("lingam.prune"):
+        b = pruning.estimate_adjacency(
+            x,
+            order,
+            method=config.prune_method,
+            threshold=config.prune_threshold,
+            **config.prune_kwargs_dict,
+        )
+    with jax.named_scope("lingam.diagnostics"):
+        xc = x - jnp.mean(x, axis=0, keepdims=True)
+        resid = xc - xc @ b.T
+        resid_var = jnp.mean(resid * resid, axis=0)
     return FitResult(order=order, adjacency=b, resid_var=resid_var)
 
 
@@ -310,18 +312,21 @@ def fit_impl_from_stats(x, mean, cov, config: FitConfig) -> FitResult:
     x = x.astype(jnp.float32)
     mean = mean.astype(jnp.float32)
     cov = cov.astype(jnp.float32)
-    var = jnp.maximum(jnp.diagonal(cov), _STATS_EPS)
-    x0 = (x - mean[None, :]) * jax.lax.rsqrt(var)[None, :]
+    with jax.named_scope("lingam.standardize"):
+        var = jnp.maximum(jnp.diagonal(cov), _STATS_EPS)
+        x0 = (x - mean[None, :]) * jax.lax.rsqrt(var)[None, :]
     order = _order_for_config(x0, config)
-    b = pruning.estimate_adjacency_from_cov(
-        cov,
-        order,
-        method=config.prune_method,
-        threshold=config.prune_threshold,
-        **config.prune_kwargs_dict,
-    )
-    r = jnp.eye(b.shape[0], dtype=b.dtype) - b
-    resid_var = jnp.maximum(jnp.einsum("ij,jk,ik->i", r, cov, r), 0.0)
+    with jax.named_scope("lingam.prune"):
+        b = pruning.estimate_adjacency_from_cov(
+            cov,
+            order,
+            method=config.prune_method,
+            threshold=config.prune_threshold,
+            **config.prune_kwargs_dict,
+        )
+    with jax.named_scope("lingam.diagnostics"):
+        r = jnp.eye(b.shape[0], dtype=b.dtype) - b
+        resid_var = jnp.maximum(jnp.einsum("ij,jk,ik->i", r, cov, r), 0.0)
     return FitResult(order=order, adjacency=b, resid_var=resid_var)
 
 

@@ -29,6 +29,8 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from . import api
 
 
@@ -62,12 +64,14 @@ class DirectLiNGAM:
         )
 
     def fit(self, x) -> "DirectLiNGAM":
-        x = jnp.asarray(x, dtype=jnp.float32)
-        result = api.fit_fn(x, self.to_config())
-        self.result_ = result
-        self.causal_order_ = np.asarray(result.order)
-        self.adjacency_ = np.asarray(result.adjacency)
-        self.resid_var_ = np.asarray(result.resid_var)
+        with obs.span("lingam.fit"):
+            x = jnp.asarray(x, dtype=jnp.float32)
+            result = api.fit_fn(x, self.to_config())
+            self.result_ = result
+            with obs.span("lingam.fetch"):
+                self.causal_order_ = np.asarray(result.order)
+                self.adjacency_ = np.asarray(result.adjacency)
+                self.resid_var_ = np.asarray(result.resid_var)
         return self
 
 

@@ -205,14 +205,22 @@ def ordering_step(x, active, reducer):
     Returns:
       (x_new, active_new, root): residualized data, updated mask, and
       the physical column index chosen this step.
+
+    Each stage runs under a ``jax.named_scope`` (``lingam.standardize``,
+    ``lingam.moments``, ``lingam.scores``, ``lingam.residual``): names in
+    the compiled program's op metadata, which a profiler trace carries
+    per device op. They add no op and leave the math unchanged.
     """
-    x_std, c, mu, var = reducer.standardize(x)
-    rows1, rows2 = reducer.moment_rows(x_std, c)
-    m1 = reducer.gather_rows(rows1)
-    m2 = reducer.gather_rows(rows2)
-    cm1, cm2 = reducer.col_moments(x_std)
-    k_list = step_scores(cm1, cm2, m1, m2, active)
-    root = jnp.argmax(k_list)
+    with jax.named_scope("lingam.standardize"):
+        x_std, c, mu, var = reducer.standardize(x)
+    with jax.named_scope("lingam.moments"):
+        rows1, rows2 = reducer.moment_rows(x_std, c)
+        m1 = reducer.gather_rows(rows1)
+        m2 = reducer.gather_rows(rows2)
+    with jax.named_scope("lingam.scores"):
+        cm1, cm2 = reducer.col_moments(x_std)
+        k_list = step_scores(cm1, cm2, m1, m2, active)
+        root = jnp.argmax(k_list)
 
     # Residualize every other active column on the root column of the
     # *unstandardized* working data (matches the sequential reference).
@@ -220,17 +228,20 @@ def ordering_step(x, active, reducer):
     # mesh: no extra psum round) for the root's moments. The covariance
     # is two-pass (centered product) for the same fp32-cancellation
     # reason as step_standardize; pad rows are masked after centering.
-    xr = x[:, root]
-    mean_r = mu[root]
-    var_r = var[root]
-    cov = reducer.mean_over_samples(
-        reducer.mask_rows((x - mu[None, :]) * (xr - mean_r)[:, None])
-    )
-    coef = cov / var_r  # (width,)
-    update = jnp.where(active & (jnp.arange(x.shape[1]) != root), coef, 0.0)
-    x_new = x - xr[:, None] * update[None, :]
+    with jax.named_scope("lingam.residual"):
+        xr = x[:, root]
+        mean_r = mu[root]
+        var_r = var[root]
+        cov = reducer.mean_over_samples(
+            reducer.mask_rows((x - mu[None, :]) * (xr - mean_r)[:, None])
+        )
+        coef = cov / var_r  # (width,)
+        update = jnp.where(
+            active & (jnp.arange(x.shape[1]) != root), coef, 0.0)
+        x_new = x - xr[:, None] * update[None, :]
+        active_new = active.at[root].set(False)
 
-    return x_new, active.at[root].set(False), root
+    return x_new, active_new, root
 
 
 def _scan_body(reducer):
@@ -334,9 +345,11 @@ def compact_order_impl(x, reducer, *, d=None, frac=0.25, min_stage=8):
         (x, active), roots = jax.lax.scan(
             body, (x, active), None, length=n_steps
         )
-        parts.append(labels[roots])
         keep = w_logical - n_steps
-        if keep:
+        with jax.named_scope("lingam.compact"):
+            parts.append(labels[roots])
+            if not keep:
+                continue
             keep_pad = _round_up(keep, col_multiple)
             # Surviving column indices in ascending order (stable under
             # vmap: distinct keys, inactive pushed past the end).
@@ -351,7 +364,8 @@ def compact_order_impl(x, reducer, *, d=None, frac=0.25, min_stage=8):
             else:
                 active = jnp.ones((keep,), dtype=bool)
             width = keep_pad
-    return jnp.concatenate(parts).astype(jnp.int32)
+    with jax.named_scope("lingam.compact"):
+        return jnp.concatenate(parts).astype(jnp.int32)
 
 
 @functools.partial(

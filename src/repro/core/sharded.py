@@ -230,21 +230,22 @@ def _finish_sharded(x, order, config: FitConfig, reducer: MeshReducer):
     m, d = x.shape
     mask_rows, rows_of, gather = _pair_row_tiles(reducer, order, d)
 
-    if config.prune_method == "ols":
-        xc = x - jnp.mean(x, axis=0, keepdims=True)
-        cov = (xc.T @ xc) / m
-        b = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
-    elif config.prune_method == "adaptive_lasso":
-        b = pruning.adaptive_lasso_adjacency(
-            x, order, **config.prune_kwargs_dict
-        )
-    else:
-        raise ValueError(f"unknown method: {config.prune_method}")
-
-    b = pruning.apply_threshold(b, config.prune_threshold)
-    xc0 = x - jnp.mean(x, axis=0, keepdims=True)
-    resid = xc0 - xc0 @ b.T
-    resid_var = jnp.mean(resid * resid, axis=0)
+    with jax.named_scope("lingam.prune"):
+        if config.prune_method == "ols":
+            xc = x - jnp.mean(x, axis=0, keepdims=True)
+            cov = (xc.T @ xc) / m
+            b = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
+        elif config.prune_method == "adaptive_lasso":
+            b = pruning.adaptive_lasso_adjacency(
+                x, order, **config.prune_kwargs_dict
+            )
+        else:
+            raise ValueError(f"unknown method: {config.prune_method}")
+        b = pruning.apply_threshold(b, config.prune_threshold)
+    with jax.named_scope("lingam.diagnostics"):
+        xc0 = x - jnp.mean(x, axis=0, keepdims=True)
+        resid = xc0 - xc0 @ b.T
+        resid_var = jnp.mean(resid * resid, axis=0)
     return b, resid_var
 
 
@@ -261,34 +262,35 @@ def _finish_sharded_scaled(
     x = x_local[:, :d]
     mask_rows, rows_of, gather = _pair_row_tiles(reducer, order, d)
 
-    mu = reducer.mean_over_samples(x)
-    xc = reducer.mask_rows(x - mu[None, :])
-    cov = reducer.gram_mean(xc)
+    with jax.named_scope("lingam.prune"):
+        mu = reducer.mean_over_samples(x)
+        xc = reducer.mask_rows(x - mu[None, :])
+        cov = reducer.gram_mean(xc)
 
-    if config.prune_method == "ols":
-        b = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
-    elif config.prune_method == "adaptive_lasso":
-        kw = config.prune_kwargs_dict
-        lam = kw.get("lam", 0.01)
-        gamma = kw.get("gamma", 1.0)
-        n_steps = kw.get("n_steps", 400)
-        var = reducer.mean_over_samples(xc * xc)
-        sd = jnp.maximum(jnp.sqrt(var), 1e-12)
-        corr = reducer.gram_mean(xc / sd[None, :])
-        b_ols = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
-        b_ols_std = b_ols * (sd[None, :] / sd[:, None])
-        w = 1.0 / jnp.maximum(jnp.abs(b_ols_std), 1e-3) ** gamma
-        lip = jnp.float32(d)
-        b_std = gather(pruning.lasso_rows(
-            corr, mask_rows, rows_of(corr), rows_of(w), lam, lip, n_steps
-        ))
-        b = b_std * (sd[:, None] / sd[None, :])
-    else:
-        raise ValueError(f"unknown method: {config.prune_method}")
-
-    b = pruning.apply_threshold(b, config.prune_threshold)
-    resid = xc - xc @ b.T  # local rows; padded rows are zero -> zero resid
-    resid_var = reducer.mean_over_samples(resid * resid)
+        if config.prune_method == "ols":
+            b = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
+        elif config.prune_method == "adaptive_lasso":
+            kw = config.prune_kwargs_dict
+            lam = kw.get("lam", 0.01)
+            gamma = kw.get("gamma", 1.0)
+            n_steps = kw.get("n_steps", 400)
+            var = reducer.mean_over_samples(xc * xc)
+            sd = jnp.maximum(jnp.sqrt(var), 1e-12)
+            corr = reducer.gram_mean(xc / sd[None, :])
+            b_ols = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
+            b_ols_std = b_ols * (sd[None, :] / sd[:, None])
+            w = 1.0 / jnp.maximum(jnp.abs(b_ols_std), 1e-3) ** gamma
+            lip = jnp.float32(d)
+            b_std = gather(pruning.lasso_rows(
+                corr, mask_rows, rows_of(corr), rows_of(w), lam, lip, n_steps
+            ))
+            b = b_std * (sd[:, None] / sd[None, :])
+        else:
+            raise ValueError(f"unknown method: {config.prune_method}")
+        b = pruning.apply_threshold(b, config.prune_threshold)
+    with jax.named_scope("lingam.diagnostics"):
+        resid = xc - xc @ b.T  # local rows; padded rows are zero -> 0
+        resid_var = reducer.mean_over_samples(resid * resid)
     return b, resid_var
 
 

@@ -117,4 +117,5 @@ def fused_moment_sums(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_moment_sums",
     )(x_raw_rows, x_raw_all, mu_rows, mu_all, rstd_rows, rstd_all, c_rows)

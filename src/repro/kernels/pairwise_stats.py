@@ -35,6 +35,10 @@ in fixed ``ACCUM_CHUNK``-wide sub-chunks, so any
 ``bm`` that is a multiple of it produces a bit-identical reduction order
 — tuned and heuristic plans differ only in speed, never in bits (the
 zero-masked padded tail contributes exact ``+0.0`` terms).
+
+Each ``pallas_call`` has a fixed ``name``: the custom call's instruction
+name in the compiled program and in a profiler trace, whatever function
+wraps it.
 """
 
 from __future__ import annotations
@@ -147,6 +151,7 @@ def pairwise_moment_sums_rows(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="pairwise_moment_sums_rows",
     )(x_rows, x_all, c_rows)
 
 
@@ -200,6 +205,7 @@ def pairwise_moments_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="pairwise_moments_pallas",
     )(x_t, x_t, c)
     inv_m = jnp.float32(1.0 / m_total)
     return m1_sum * inv_m, m2_sum * inv_m

@@ -6,7 +6,9 @@ Three jit-safe primitives, wired through every layer of the repo:
     (``with obs.span("ordering.step", d=d): ...``). Off by default;
     enable with :func:`enable` or ``REPRO_OBS=1``. Spans never stage
     anything into traced programs: instrumented and uninstrumented runs
-    produce bit-identical results and identical compile counts.
+    produce bit-identical results and identical compile counts. Inside
+    ``jax.profiler.trace`` every span is also a profiler annotation of
+    the same name, on the device trace's clock.
   * :mod:`repro.obs.metrics` — process-local counters / gauges /
     histograms with p50/p95/p99 summaries, exported via
     :func:`repro.obs.metrics.snapshot` or
@@ -19,8 +21,7 @@ Three jit-safe primitives, wired through every layer of the repo:
   * :mod:`repro.obs.profile` — performance accounting on top of the
     other three: per-program ``cost_analysis()`` FLOPs/bytes and
     ``memory_analysis()`` watermarks keyed like the compile log,
-    roofline utilization against the device-peaks registry, and
-    ``device_trace()`` for span-annotated ``jax.profiler`` timelines.
+    and roofline utilization against the device-peaks registry.
     Off by default; enable with :func:`repro.obs.profile.enable` or
     ``REPRO_OBS_PROFILE=1``.
 
@@ -44,8 +45,6 @@ from .trace import (  # noqa: F401  (re-exported convenience surface)
     reset,
     roots,
     span,
-    to_chrome_trace,
-    write_chrome_trace,
 )
 
 __all__ = [
@@ -64,8 +63,6 @@ __all__ = [
     "reset_all",
     "roots",
     "span",
-    "to_chrome_trace",
-    "write_chrome_trace",
 ]
 
 

@@ -1,5 +1,5 @@
-"""Performance accounting: cost capture, memory watermarks, roofline
-utilization, and profiler-correlated device traces.
+"""Performance accounting: cost capture, memory watermarks and roofline
+utilization.
 
 This is the fourth telemetry primitive (after spans, metrics, and the
 compile log): it answers *how close to the hardware* the compiled
@@ -19,10 +19,10 @@ programs run, not just how long they took.
     :func:`utilization` turns (flops, bytes, seconds) into achieved
     GFLOP/s, GB/s, arithmetic intensity, and fraction-of-roofline;
     every timed :func:`call` feeds these into ``obs.metrics`` gauges.
-  * **Device-trace correlation** — :func:`device_trace` wraps
-    ``jax.profiler.trace`` and mirrors host span names into device
-    ``TraceAnnotation``s, so the host span tree and the device timeline
-    line up in one Perfetto view.
+
+For the host spans and device ops on one timeline, run the work inside
+``jax.profiler.trace``: every ``obs.span`` is then a profiler
+annotation (:mod:`repro.obs.trace`).
 
 Profiling is **off by default** — enable with :func:`enable` or
 ``REPRO_OBS_PROFILE=1``. Disabled, :func:`call` is a plain passthrough
@@ -36,7 +36,6 @@ zero-delta pin.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import os
@@ -45,7 +44,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from . import compile_log, metrics, trace
+from . import compile_log, metrics
 
 _ENV_VAR = "REPRO_OBS_PROFILE"
 _PEAKS_ENV = "REPRO_PEAKS"
@@ -503,38 +502,3 @@ def reset() -> None:
     """Drop every captured cost record (tests / fresh windows)."""
     with _lock:
         _records.clear()
-
-
-# ---------------------------------------------------------------------------
-# Device-trace correlation
-# ---------------------------------------------------------------------------
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """Correlated host+device profiling window.
-
-    Wraps ``jax.profiler.trace(log_dir)`` (the Perfetto/XPlane device
-    timeline) and, for its duration, mirrors every host span into a
-    ``jax.profiler.TraceAnnotation`` of the same name — so the span tree
-    rendered by ``obs.format_tree``/``write_chrome_trace`` and the
-    device trace under ``log_dir`` align on names in one Perfetto view.
-    No-op (plain yield) when profiling is disabled; span mirroring also
-    requires spans, i.e. ``obs.enable()``.
-    """
-    if not _ENABLED:
-        yield
-        return
-    import jax
-
-    os.makedirs(log_dir, exist_ok=True)
-
-    def hook(name: str):
-        return jax.profiler.TraceAnnotation(name)
-
-    trace.set_annotation_hook(hook)
-    try:
-        with jax.profiler.trace(log_dir):
-            yield
-    finally:
-        trace.set_annotation_hook(None)

@@ -19,8 +19,19 @@ state — compile-event accounting, not steady-state latency.
 
 Telemetry is **off by default**. Enable with :func:`enable` or the
 ``REPRO_OBS=1`` environment variable; when disabled, :func:`span`
-returns a shared no-op context manager (one flag test, no allocation),
-so the instrumented hot paths cost nothing.
+returns a shared no-op context manager (one flag test and one check
+for a profiler session, no allocation), so the instrumented hot paths
+cost nothing.
+
+**Profiler view.** While a ``jax.profiler`` session is active (inside
+``jax.profiler.trace``), every span is also written into the profiler's
+trace as a ``jax.profiler.TraceAnnotation`` of the same name, with no
+attributes: host spans and device ops then share one clock, and a
+device idle gap can be charged to the span open at that moment. With
+telemetry off the annotation is all a span is; with telemetry on the
+:class:`Span` enters it itself. The fit's own spans follow the
+``lingam.<stage>`` naming of the program's device scopes
+(``lingam.fit``, ``lingam.fetch``).
 
 Completed root spans are kept in a bounded ring (newest last); render
 them with :func:`format_tree`.
@@ -53,18 +64,25 @@ class _Stack(threading.local):
 
 _stack = _Stack()
 
-# Optional span mirror: when set (by obs.profile.device_trace), every
-# entered span calls it with the span name and enters the returned
-# context manager — a jax.profiler.TraceAnnotation — so host spans show
-# up on the device timeline under the same names. None (the default)
-# costs one attribute read per span.
-_annotation_hook = None
+# A jax.profiler.TraceAnnotation that takes span attributes (and drops
+# them), made on first use so that importing telemetry does not import
+# jax.
+_annotation = None
 
 
-def set_annotation_hook(fn) -> None:
-    """Install/clear (``None``) the span->device-annotation mirror."""
-    global _annotation_hook
-    _annotation_hook = fn
+def _profiler_annotation():
+    """The annotation class while a profiler session is active, else
+    None."""
+    global _annotation
+    if _annotation is None:
+        import jax.profiler
+
+        class Annotation(jax.profiler.TraceAnnotation):
+            def set(self, **attrs) -> "Annotation":
+                return self
+
+        _annotation = Annotation
+    return _annotation if _annotation.is_enabled() else None
 
 
 def enable(on: bool = True) -> None:
@@ -111,12 +129,10 @@ class Span:
 
     def __enter__(self) -> "Span":
         self.traced = _in_jax_trace()
-        if _annotation_hook is not None:
-            try:
-                self._ann = _annotation_hook(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        annotation = _profiler_annotation()
+        if annotation is not None:
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         _stack.spans.append(self)
         self.t0 = time.perf_counter()
         return self
@@ -124,10 +140,8 @@ class Span:
     def __exit__(self, exc_type, exc, tb) -> None:
         self.duration_s = time.perf_counter() - self.t0
         if self._ann is not None:
-            try:
-                self._ann.__exit__(exc_type, exc, tb)
-            finally:
-                self._ann = None
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         stack = _stack.spans
@@ -162,9 +176,12 @@ _NOOP = _NoopSpan()
 
 
 def span(name: str, **attrs):
-    """A timed host-side span (no-op unless telemetry is enabled)."""
+    """A timed host-side span. With telemetry off it is the profiler's
+    annotation ``name`` while a profiler session is active, else the
+    shared no-op."""
     if not _ENABLED:
-        return _NOOP
+        annotation = _profiler_annotation()
+        return _NOOP if annotation is None else annotation(name)
     return Span(name, attrs)
 
 
@@ -205,45 +222,3 @@ def format_tree(last: Optional[int] = None) -> str:
     for s in roots(last):
         _fmt_span(s, 0, lines)
     return "\n".join(lines) if lines else "(no spans recorded)"
-
-
-def to_chrome_trace(last: Optional[int] = None) -> Dict[str, Any]:
-    """Finished span trees as Chrome/Perfetto trace-event JSON.
-
-    Every span becomes one complete ("ph": "X") event with microsecond
-    timestamps rebased to the earliest recorded root, so the file drops
-    straight into ``chrome://tracing`` / https://ui.perfetto.dev.
-    Span attributes land in ``args`` (stringified — trace viewers want
-    flat JSON scalars); spans that ran at jax trace time keep their
-    ``traced`` tag as the event category.
-    """
-    spans = roots(last)
-    base = min((s.t0 for s in spans), default=0.0)
-    events: List[Dict[str, Any]] = []
-
-    def emit(s: Span) -> None:
-        events.append({
-            "name": s.name,
-            "cat": "jax-trace" if s.traced else "host",
-            "ph": "X",
-            "ts": (s.t0 - base) * 1e6,
-            "dur": s.duration_s * 1e6,
-            "pid": 0,
-            "tid": 0,
-            "args": {k: str(v) for k, v in s.attrs.items()},
-        })
-        for c in s.children:
-            emit(c)
-
-    for s in spans:
-        emit(s)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(path: str, last: Optional[int] = None) -> str:
-    """Serialize :func:`to_chrome_trace` to ``path``; returns the path."""
-    import json
-
-    with open(path, "w") as f:
-        json.dump(to_chrome_trace(last), f, indent=1)
-    return path

@@ -202,38 +202,6 @@ def test_prometheus_escapes_label_values():
     assert "\nmismatch" not in text  # no raw newline inside a sample
 
 
-def test_chrome_trace_events():
-    obs.enable()
-    with obs.span("serve.flush", n_due=3):
-        with obs.span("serve.flush_bucket", shape=(6, 6)):
-            time.sleep(0.002)
-    doc = obs.to_chrome_trace()
-    assert doc["displayTimeUnit"] == "ms"
-    by_name = {e["name"]: e for e in doc["traceEvents"]}
-    assert set(by_name) == {"serve.flush", "serve.flush_bucket"}
-    outer, inner = by_name["serve.flush"], by_name["serve.flush_bucket"]
-    for e in (outer, inner):
-        assert e["ph"] == "X"
-        assert e["cat"] in ("host", "jax-trace")
-        assert e["pid"] == 0 and e["tid"] == 0
-    # Child nests inside the parent on the timeline, timestamps
-    # rebased to the earliest root.
-    assert outer["ts"] == 0.0
-    assert inner["ts"] >= outer["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1.0
-    assert inner["args"] == {"shape": "(6, 6)"}  # attrs stringified
-
-
-def test_write_chrome_trace_roundtrip(tmp_path):
-    obs.enable()
-    with obs.span("fit", d=4):
-        pass
-    path = obs.write_chrome_trace(str(tmp_path / "trace.json"))
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["traceEvents"][0]["name"] == "fit"
-
-
 # ---------------------------------------------------------------------------
 # BoundedRing
 # ---------------------------------------------------------------------------

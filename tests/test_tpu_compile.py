@@ -9,6 +9,7 @@ process at a time), and the tests skip only there.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,3 +94,19 @@ def test_staged_local_fit_compiles_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") > 1
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+def test_fit_kernel_keeps_its_name_under_the_moments_scope(one_chip):
+    """In the compiled fit, the pair-tile kernel's custom call has its
+    fixed name and carries the ``lingam.moments`` scope in its op_name:
+    what a device trace reads per op."""
+    cfg = api.FitConfig(compaction="staged", **_PALLAS)
+    text = api._fit_local.lower(_sds(one_chip, 512, 64), cfg).compile(
+    ).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert line.lstrip().startswith("%pairwise_moments_pallas"), line
+        (op_name,) = re.findall(r'op_name="([^"]*)"', line)
+        assert "/lingam.moments/" in op_name, op_name
