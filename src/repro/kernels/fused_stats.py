@@ -21,44 +21,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pairwise_stats import _accumulate
-
-EPS = 1e-12
-LOG2 = 0.6931471805599453
+from .pairwise_stats import check_blocks, moment_sums
 
 
 def _fused_kernel(x_i_ref, x_j_ref, mu_i_ref, mu_j_ref, rs_i_ref, rs_j_ref,
-                  c_ref, m1_ref, m2_ref, *, bm, m_total):
-    k = pl.program_id(2)
+                  c_ref, m1_ref, m2_ref, **extents):
+    # Standardize the raw tiles in VMEM (affine per variable row).
+    def x_i(lanes):
+        return (x_i_ref[:, lanes].astype(jnp.float32) - mu_i_ref[...]) \
+            * rs_i_ref[...]
 
-    @pl.when(k == 0)
-    def _init():
-        m1_ref[...] = jnp.zeros_like(m1_ref)
-        m2_ref[...] = jnp.zeros_like(m2_ref)
+    def x_j(lanes):
+        return (x_j_ref[:, lanes].astype(jnp.float32) - mu_j_ref[...]) \
+            * rs_j_ref[...]
 
-    # Standardize raw tiles in VMEM (affine per variable row).
-    xi = x_i_ref[...].astype(jnp.float32)  # (BI, BM) raw
-    xj = x_j_ref[...].astype(jnp.float32)  # (BJ, BM) raw
-    xi = (xi - mu_i_ref[...]) * rs_i_ref[...]  # (BI, 1) constants
-    xj = (xj - mu_j_ref[...]) * rs_j_ref[...]  # (BJ, 1) constants
-    c = c_ref[...].astype(jnp.float32)     # (BI, BJ)
-
-    sample_ids = k * bm + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bm), 2)
-    valid = sample_ids < m_total
-
-    inv_std = jax.lax.rsqrt(jnp.maximum(1.0 - c * c, EPS))
-    r = xi[:, None, :] - c[:, :, None] * xj[None, :, :]
-    u = r * inv_std[:, :, None]
-    u = jnp.where(valid, u, 0.0)
-
-    au = jnp.abs(u)
-    logcosh = au + jnp.log1p(jnp.exp(-2.0 * au)) - LOG2
-    logcosh = jnp.where(valid, logcosh, 0.0)
-    uexp = u * jnp.exp(-0.5 * u * u)
-
-    # Fixed-width sample sub-sums (see pairwise_stats._accumulate): the
-    # reduction order is independent of the tuned bm block.
-    _accumulate(m1_ref, m2_ref, logcosh, uexp, bm)
+    moment_sums(x_i, x_j, c_ref, m1_ref, m2_ref, **extents)
 
 
 @functools.partial(
@@ -90,7 +67,7 @@ def fused_moment_sums(
     """
     tile, m_pad = x_raw_rows.shape
     d_pad = x_raw_all.shape[0]
-    assert tile % bi == 0 and d_pad % bj == 0 and m_pad % bm == 0
+    check_blocks(tile, d_pad, m_pad, m_total, bi, bj, bm)
     grid = (tile // bi, d_pad // bj, m_pad // bm)
     kernel = functools.partial(_fused_kernel, bm=bm, m_total=m_total)
     out_shape = [

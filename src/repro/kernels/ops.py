@@ -126,7 +126,8 @@ def pairwise_moments(
             c.astype(jnp.float32), ((0, d_pad - d), (0, d_pad - d))
         )
         m1, m2 = pairwise_stats.pairwise_moments_pallas(
-            xt, c_pad, m_total=m, bi=bi, bj=bj, bm=bm, interpret=interpret
+            xt, c_pad, m_total=m, d_total=d, bi=bi, bj=bj, bm=bm,
+            interpret=interpret,
         )
         return m1[:d, :d], m2[:d, :d]
     raise ValueError(f"unknown backend: {plan.backend}")
@@ -179,9 +180,9 @@ def pairwise_moment_sums_rows(
         tile_pad = _round_up(tile, bi)
         # Pad variables and samples to block multiples: padded rows and
         # columns are sliced back off below, padded samples are masked
-        # via m_total (and contribute exact zeros to the sub-sums). The
-        # padded rows also hold a tile_pad slice from any valid
-        # row_start (<= d - tile), so dynamic_slice never clamps it.
+        # or skipped via m_total. The padded rows also hold a tile_pad
+        # slice from any valid row_start (<= d - tile), so dynamic_slice
+        # never clamps it.
         d_pad, bj = tune.padded_extent(d + tile_pad - tile, bi, plan.bj)
         m_pad = _round_up(m_local, bm)
         xt_all = jnp.pad(x_std.T, ((0, d_pad - d), (0, m_pad - m_local)))

@@ -1,9 +1,8 @@
 """Timed block-shape search: candidates -> measurements -> TunePlan.
 
-The candidate generator emits MXU/VPU-aligned ``(BI, BJ, BM)`` grids
-bounded by the VMEM working-set model documented on
-:func:`repro.kernels.tune.registry.vmem_bytes` (the bound the old
-``ops._pick_blocks`` heuristic encoded statically); every sample-axis
+The candidate generator emits the ``(BI, BJ, BM)`` plans the kernel
+variant admits, bounded by the VMEM working-set model documented on
+:func:`repro.kernels.tune.registry.vmem_bytes`; every sample-axis
 block is a multiple of :data:`~repro.kernels.tune.registry.ACCUM_CHUNK`
 so all candidates share one fp32 reduction order — tuned plans are
 bit-identical to the heuristic, just faster. The search harness times
@@ -28,8 +27,7 @@ from repro.obs import profile as obs_profile
 from . import cache as tune_cache
 from . import registry
 
-_BI_GRID = (8, 16)
-_BJ_GRID = (128, 256)  # lane multiples; at most 128 columns take one block
+_BI_GRID = (8, 16, 32, 64)  # sublane multiples that divide 128
 _BM_GRID = (128, 256, 512, 1024, 2048)
 
 
@@ -107,7 +105,11 @@ def candidate_plans(
     chunk: Optional[int] = None,
     quick: bool = False,
 ) -> List[registry.Plan]:
-    """Aligned, VMEM-bounded, bit-stable candidate grid for one op.
+    """Aligned, VMEM-bounded, bit-stable candidate grid for one op: the
+    plans the variant's ``validate`` admits (for the Pallas kernels:
+    ``bj`` one 128-lane tile, ``bi`` dividing 128, ``bm`` a multiple of
+    ``ACCUM_CHUNK``, the working set of
+    :func:`~repro.kernels.tune.registry.vmem_bytes` within the budget).
 
     The heuristic plan is always included (dedup'd), so a tuned plan is
     never slower than the fallback the search replaces.
@@ -122,38 +124,28 @@ def candidate_plans(
     def add(**kw):
         p = dataclasses.replace(heur, source="candidate", **kw)
         sig = (p.bi, p.bj, p.bm, p.block)
-        if sig in seen:
+        if sig in seen or not variant.validate(p, shape, chunk):
             return
         seen.add(sig)
         plans.append(p)
 
     tunable = set(cons.tunable)
-    d_axis = shape[1]  # d for every pair op: (m, d) or (tile, d, m)
-    bj_grid = (
-        _BJ_GRID if d_axis > registry._LANE
-        else (registry.lane_block(d_axis),)
-    )
+    # One column block: a 128-lane tile, or every column up to 128 — a
+    # wider block pads the pair extent further at the staged widths.
+    bj = registry.lane_block(shape[1])  # d for every pair op
+    bi_grid = _BI_GRID[:1] if quick else _BI_GRID
     if tunable >= {"bi", "bj", "bm"}:
         m_axis = shape[0] if len(shape) == 2 else shape[2]
-        bi_grid = _BI_GRID[:1] if quick else _BI_GRID
         bm_grid = [
             bm for bm in (_BM_GRID[:2] if quick else _BM_GRID)
-            if bm % cons.accum_chunk == 0
-            and (not chunk or bm <= chunk)
-            and bm <= registry._round_up(m_axis, cons.accum_chunk)
+            if bm <= registry._round_up(m_axis, cons.accum_chunk)
         ]
         for bi in bi_grid:
-            for bj in bj_grid:
-                for bm in bm_grid:
-                    if registry.vmem_bytes(bi, bj, bm) > cons.vmem_budget:
-                        continue
-                    add(bi=bi, bj=bj, bm=bm)
+            for bm in bm_grid:
+                add(bi=bi, bj=bj, bm=bm)
     elif tunable == {"bi", "bj"}:
-        for bi in (_BI_GRID[:1] if quick else _BI_GRID):
-            for bj in bj_grid:
-                if registry.vmem_bytes(bi, bj, heur.bm) > cons.vmem_budget:
-                    continue
-                add(bi=bi, bj=bj)
+        for bi in bi_grid:
+            add(bi=bi, bj=bj)
     elif tunable == {"block"}:
         d = shape[1]
         cap = registry._round_up(max(d, 1), cons.sublane)

@@ -26,12 +26,13 @@ Resolution order inside ``dispatch``:
 
 **Bit-parity contract.** Tuned and heuristic plans for the same op
 produce bit-identical moment outputs: block shapes only re-tile the
-(i, j) pair space (per-element arithmetic untouched), and the kernels
-accumulate the sample axis in fixed :data:`ACCUM_CHUNK`-wide sub-chunks,
-so any ``bm`` that is a multiple of ``ACCUM_CHUNK`` yields the same fp32
-reduction order (zero-padded tails add exact ``+0.0``). The candidate
-generator only emits such ``bm``; ``tests/test_tune.py`` pins the
-parity.
+(i, j) pair space (per-element arithmetic untouched; pairs past the
+valid extents are skipped, not computed), and the kernels accumulate the
+sample axis in fixed :data:`ACCUM_CHUNK`-wide sub-chunks, in sample
+order, so any ``bm`` that is a multiple of ``ACCUM_CHUNK`` yields the
+same fp32 reduction order (padded samples are masked or skipped). The
+candidate generator only emits such ``bm``; ``tests/test_tune.py`` pins
+the parity.
 """
 
 from __future__ import annotations
@@ -54,7 +55,9 @@ ACCUM_CHUNK = 128
 
 _SUBLANE = 8      # fp32 second-to-last-dim tile
 _LANE = 128       # last-dim tile / VPU lane width
-_VMEM_BUDGET = int(4.5 * 1024 * 1024)  # bytes; see vmem_bytes()
+#: v5e's default scoped-VMEM limit (16 MiB) less headroom for Mosaic's
+#: own scratch; see vmem_bytes().
+_VMEM_BUDGET = 14 * 1024 * 1024
 
 _MODES = ("off", "cache", "auto")
 
@@ -76,10 +79,18 @@ def _trace_state_clean() -> bool:
 
 
 def vmem_bytes(bi: int, bj: int, bm: int) -> int:
-    """fp32 VMEM working set of one (BI, BJ, BM) grid cell: the two
-    streamed input blocks plus the two (BI, BJ, BM) moment
-    intermediates (residual/nonlinearity tensors)."""
-    return 4 * (bi * bm + bj * bm + 2 * bi * bj * bm)
+    """fp32 VMEM working set of one (BI, BJ, BM) grid cell of the moment
+    kernels (``pairwise_stats.moment_sums``): the double-buffered input
+    blocks (x_i, x_j, correlations) and output blocks, and the residual
+    and integrand tensors of the (BI, BJ, ACCUM_CHUNK) sample chunks the
+    kernel forms one by one. Mosaic keeps about ten such chunk tensors
+    live (compiled for v5e: 24.3 MB at (32, 128, 2048), 36.0 MB at
+    (64, 128, 1024)); the model counts twelve."""
+    return 4 * (
+        2 * (bi * bm + bj * bm + bi * bj)   # inputs, double-buffered
+        + 2 * 2 * bi * bj                   # two outputs, double-buffered
+        + 12 * bi * bj * ACCUM_CHUNK        # live chunk tensors
+    )
 
 
 def lane_block(d: int) -> int:
@@ -223,11 +234,15 @@ def get_variant(op: str, backend: str) -> KernelVariant:
 
 
 def heuristic_pair_blocks(d: int, m: int) -> Tuple[int, int, int]:
-    """Lane-legal pair-tile block shapes, VMEM-bounded.
+    """Lane-legal pair-tile block shapes, VMEM-bounded (:func:`vmem_bytes`).
 
-    The (BI, BJ, BM) intermediate is the VMEM working set (see
-    :func:`vmem_bytes`); ``bj`` is one 128-lane tile, or every column
-    when there are at most 128 (:func:`lane_block`).
+    ``bj`` is one 128-lane tile, or every column when there are at most
+    128 (:func:`lane_block`): a wider column block pads the pair extent
+    further at the staged widths. The kernel is straight-line code over
+    the ``bm / 128`` sample chunks of a block, so ``bm`` trades grid
+    steps against compile time: 4,096 ran the 4,096 x 964 kernel 5%
+    faster on a v5e than 2,048, but the staged fit, which compiles one
+    kernel per stage width, then takes minutes to compile.
     """
     bi, bj = _SUBLANE, lane_block(d)
     if m >= 4096:
@@ -260,9 +275,9 @@ def _pair_blocked_heuristic(shape, chunk=None) -> Plan:
 def _rows_pallas_heuristic(shape, chunk=None) -> Plan:
     tile, d, m = shape
     # The sample block is the caller's chunk when that is lane-aligned
-    # and tiles m, else the whole sample extent (the only other block
-    # the TPU accepts).
-    bm = chunk if chunk and chunk % _LANE == 0 and m % chunk == 0 else m
+    # and tiles m, else the whole sample extent in ACCUM_CHUNK chunks.
+    bm = (chunk if chunk and chunk % _LANE == 0 and m % chunk == 0
+          else _round_up(max(m, 1), ACCUM_CHUNK))
     return Plan(
         op="pairwise_moment_sums_rows", variant="pallas-row-tile",
         backend="pallas", bi=_SUBLANE, bj=lane_block(d), bm=bm,
@@ -305,17 +320,22 @@ def _validate_pallas(plan: Plan, shape, chunk=None) -> bool:
     """A tuned Pallas plan is admissible for this shape when the TPU
     accepts its blocks (rows a sublane multiple; columns a lane
     multiple, or one block over at most 128 columns — see
-    :func:`padded_extent`), it is bit-stable (bm a multiple of the
-    accumulation chunk) and within the chunk memory bound when one
-    applies. Divisibility is *not* required — the ops wrappers pad to
-    the plan's blocks. ``shape[1]`` is d for every pair op."""
+    :func:`padded_extent`), it pads the pair extent no further than the
+    heuristic (``bi`` divides 128; ``bj`` one 128-lane tile over more than
+    128 columns), it is bit-stable (bm a multiple of the accumulation
+    chunk), its working set fits the VMEM budget, and it is within the
+    chunk memory bound when one applies. Divisibility is *not* required —
+    the ops wrappers pad to the plan's blocks. ``shape[1]`` is d for
+    every pair op."""
     if plan.bi < 1 or plan.bj < 1 or plan.bm < 1:
         return False
-    if plan.bi % _SUBLANE:
+    if plan.bi % _SUBLANE or _LANE % plan.bi:
         return False
-    if plan.bj % _LANE and shape[1] > _LANE:
+    if shape[1] > _LANE and plan.bj != _LANE:
         return False
     if plan.bm % ACCUM_CHUNK:
+        return False
+    if vmem_bytes(plan.bi, plan.bj, plan.bm) > _VMEM_BUDGET:
         return False
     if chunk and plan.bm > chunk:
         return False

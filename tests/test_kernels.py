@@ -59,7 +59,7 @@ def test_pallas_block_shape_sweep(bi, bj, bm):
     xt = jnp.pad(xs.T, ((0, d_pad - d), (0, m_pad - m)))
     cp = jnp.pad(c, ((0, d_pad - d), (0, d_pad - d)))
     m1p, m2p = pairwise_moments_pallas(
-        xt, cp, m_total=m, bi=bi, bj=bj, bm=bm, interpret=True
+        xt, cp, m_total=m, d_total=d, bi=bi, bj=bj, bm=bm, interpret=True
     )
     _offdiag_close(m1r, m1p[:d, :d], d, atol=2e-6)
     _offdiag_close(m2r, m2p[:d, :d], d, atol=2e-6)
@@ -88,6 +88,84 @@ def test_bf16_input_upcast():
     )
     # bf16 data has ~3 decimal digits; moments agree loosely.
     _offdiag_close(m1r, m1p, d, atol=1e-2)
+
+
+# The shared moment sums against the oracle, through both kernels that
+# run them. (m, d, bi, bm): samples that fill the sample blocks exactly
+# (no mask is emitted), a ragged last block after three full ones, and row
+# blocks of 8, 32 and 128 one variable past a lane tile (the pair-tile
+# grid stops at the last row block that holds a variable).
+_MOMENT_CASES = {
+    "filled": (512, 20, 8, 256),
+    "ragged": (3 * 256 + 37, 20, 8, 256),
+    "bi8_d129": (300, 129, 8, 128),
+    "bi32_d129": (300, 129, 32, 128),
+    "bi128_d129": (300, 129, 128, 128),
+}
+
+
+def _kernel_masks(fn, *args):
+    """Number of selects anywhere in the kernel body: the sample masks."""
+    import jax
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["jaxpr"]
+            for sub in _subjaxprs(eqn.params):
+                yield from kernels(sub)
+
+    def selects(jaxpr):
+        return sum(
+            (eqn.primitive.name == "select_n")
+            + sum(selects(sub) for sub in _subjaxprs(eqn.params))
+            for eqn in jaxpr.eqns
+        )
+
+    (body,) = kernels(jax.make_jaxpr(fn)(*args).jaxpr)
+    return selects(body)
+
+
+@pytest.mark.parametrize("kernel", ["pair", "rows"])
+@pytest.mark.parametrize("case", sorted(_MOMENT_CASES))
+def test_moment_kernels_match_oracle(kernel, case):
+    from repro.kernels.tune import Plan
+
+    m, d, bi, bm = _MOMENT_CASES[case]
+    xs, c = _make(m, d)
+    m1r, m2r = ref.pairwise_moments_ref(xs, c)
+    bj = 128 if d > 128 else d + (-d) % 8
+    if kernel == "pair":
+        plan = Plan(op="pairwise_moments", variant="pallas-pair-tile",
+                    backend="pallas", bi=bi, bj=bj, bm=bm, source="override")
+
+        def fn(x, c):
+            return ops.pairwise_moments(
+                x, c, backend="pallas", interpret=True, plan=plan)
+
+        m1, m2 = fn(xs, c)
+        _offdiag_close(m1r, m1, d, atol=2e-6)
+        _offdiag_close(m2r, m2, d, atol=2e-6)
+    else:
+        plan = Plan(op="pairwise_moment_sums_rows", variant="pallas-row-tile",
+                    backend="pallas", bi=bi, bj=bj, bm=bm, source="override")
+        start, tile = 1, d - 1  # a tile that straddles the row blocks
+
+        def fn(x, c):
+            return ops.pairwise_moment_sums_rows(
+                x, c, start, tile, backend="pallas", interpret=True,
+                plan=plan)
+
+        s1, s2 = fn(xs, c)
+        assert s1.shape == (tile, d)
+        mask = 1.0 - np.eye(d)[start:]
+        for got, want in ((s1, m1r), (s2, m2r)):
+            np.testing.assert_allclose(
+                np.asarray(got) * mask, np.asarray(want)[start:] * m * mask,
+                atol=2e-6 * m, rtol=0,
+            )
+    # a mask only where the last sample block is ragged
+    assert (_kernel_masks(fn, xs, c) > 0) == (m % bm != 0)
 
 
 # Padding edges: tile / d / m just above and below the block multiples

@@ -50,11 +50,23 @@ def _sds(sharding, *shape):
 _PALLAS = dict(backend="pallas", interpret=False)
 
 # (case, kernel call, argument shapes): the widths the main path runs.
+# Each compiles with the dispatcher's heuristic plan; a plan whose working
+# set overflows the chip's scoped VMEM fails here (RESOURCE_EXHAUSTED).
 _KERNELS = {
     # gene-964: d_pad 1024, m_pad 65,536, blocks (8, 128, 2048).
     "pair_gene964": (
         lambda x, c: ops.pairwise_moments(x, c, **_PALLAS),
         [(65_164, 964), (964, 964)],
+    ),
+    # The benchmark's cells: gene-964 at 4,096 rows (blocks fill the
+    # samples exactly) and stocks-487 (3,999 rows, a masked last chunk).
+    "pair_4096x964": (
+        lambda x, c: ops.pairwise_moments(x, c, **_PALLAS),
+        [(4096, 964), (964, 964)],
+    ),
+    "pair_3999x487": (
+        lambda x, c: ops.pairwise_moments(x, c, **_PALLAS),
+        [(3999, 487), (487, 487)],
     ),
     # 1m-100 width, one 2,048-sample block: one 104-column block.
     "pair_d100": (
