@@ -210,13 +210,21 @@ jax.tree_util.register_dataclass(
 )
 
 
-def _order_for_config(x, config: FitConfig):
-    reducer = ordering.LocalReducer(
-        backend=config.backend,
-        interpret=config.interpret,
-        moment_chunk=config.moment_chunk,
-        tune=config.tune,
-    )
+def _order_for_config(x, config: FitConfig, valid=None):
+    if valid is None:
+        reducer = ordering.LocalReducer(
+            backend=config.backend,
+            interpret=config.interpret,
+            moment_chunk=config.moment_chunk,
+            tune=config.tune,
+        )
+    else:
+        reducer = ordering.MaskedReducer(
+            valid,
+            backend=config.backend,
+            interpret=config.interpret,
+            tune=config.tune,
+        )
     if config.compaction == "none":
         return ordering.masked_order_impl(x, reducer)
     if config.compaction == "staged":
@@ -229,7 +237,7 @@ def _order_for_config(x, config: FitConfig):
     raise ValueError(f"unknown compaction: {config.compaction}")
 
 
-def finish_fit(x, order, config: FitConfig) -> FitResult:
+def finish_fit(x, order, config: FitConfig, valid=None) -> FitResult:
     """Adjacency + residual diagnostics given the causal order.
 
     Shared tail of every plan: the mesh plan runs the sharded ordering
@@ -237,7 +245,9 @@ def finish_fit(x, order, config: FitConfig) -> FitResult:
     the pair axis via ``pruning.ols_rows`` — identical per-row
     arithmetic, so the plans' coefficients agree to the ulp-level
     lowering differences of batched solves (exactly, at the parity
-    cells the tests pin).
+    cells the tests pin). With ``valid`` (interventional data), each
+    row's regression and residual variance run over the samples valid
+    for its variable.
     """
     with jax.named_scope("lingam.prune"):
         b = pruning.estimate_adjacency(
@@ -245,18 +255,27 @@ def finish_fit(x, order, config: FitConfig) -> FitResult:
             order,
             method=config.prune_method,
             threshold=config.prune_threshold,
+            valid=valid,
             **config.prune_kwargs_dict,
         )
     with jax.named_scope("lingam.diagnostics"):
         xc = x - jnp.mean(x, axis=0, keepdims=True)
         resid = xc - xc @ b.T
-        resid_var = jnp.mean(resid * resid, axis=0)
+        if valid is None:
+            resid_var = jnp.mean(resid * resid, axis=0)
+        else:
+            n = jnp.maximum(jnp.sum(valid, axis=0), 1.0)
+            resid = resid - jnp.sum(valid * resid, axis=0) / n
+            resid_var = jnp.sum(valid * resid * resid, axis=0) / n
     return FitResult(order=order, adjacency=b, resid_var=resid_var)
 
 
-def fit_impl(x, config: FitConfig) -> FitResult:
+def fit_impl(x, config: FitConfig, intervened=None) -> FitResult:
     """Unjitted trace body of the local plan (for callers composing
     larger programs — ``vmap`` in the batched engine, ...).
+
+    ``intervened`` (optional, (m, d) bool) marks the samples in which an
+    intervention set the variable; see :func:`fit_fn`.
 
     The stage spans here execute at *trace time* only (once per
     compile; tagged ``[trace]`` in the span tree) — they account for
@@ -265,19 +284,45 @@ def fit_impl(x, config: FitConfig) -> FitResult:
     """
     compile_log.record("core.fit", shape=x.shape, config=config)
     x = x.astype(jnp.float32)
+    valid = None
+    if intervened is not None:
+        valid = jnp.logical_not(intervened).astype(jnp.float32)
     with obs.span("fit.ordering", d=x.shape[-1],
                   compaction=config.compaction):
-        order = _order_for_config(x, config)
+        order = _order_for_config(x, config, valid)
     with obs.span("fit.pruning", method=config.prune_method):
-        return finish_fit(x, order, config)
+        return finish_fit(x, order, config, valid)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
-def _fit_local(x, config: FitConfig) -> FitResult:
-    return fit_impl(x, config)
+def _fit_local(x, config: FitConfig, intervened=None) -> FitResult:
+    return fit_impl(x, config, intervened)
 
 
-def fit_fn(x, config: FitConfig = FitConfig()) -> FitResult:
+def _check_intervened(x, intervened, config: FitConfig):
+    """Reject what the interventional fit does not run: another shape
+    than the data's, a mesh partition, moment chunking, or another
+    pruner than OLS."""
+    if tuple(intervened.shape) != tuple(x.shape):
+        raise ValueError(
+            f"intervened has shape {tuple(intervened.shape)}, the data "
+            f"{tuple(x.shape)}; it needs one flag per sample and variable"
+        )
+    if config.partition is not None:
+        raise ValueError(
+            "the interventional fit runs the local plan only; the mesh "
+            "plan takes no intervened mask — drop config.partition")
+    if config.moment_chunk is not None:
+        raise ValueError(
+            "the interventional fit has no chunked moments; drop "
+            "config.moment_chunk")
+    if config.prune_method != "ols":
+        raise ValueError(
+            "the interventional fit prunes by OLS only, got "
+            f"prune_method={config.prune_method!r}")
+
+
+def fit_fn(x, config: FitConfig = FitConfig(), intervened=None) -> FitResult:
     """Pure DirectLiNGAM fit: (m, d) data + static config -> FitResult.
 
     The entire fit is one traced program (ordering scan, adjacency solve,
@@ -287,8 +332,48 @@ def fit_fn(x, config: FitConfig = FitConfig()) -> FitResult:
     the same ``FitResult`` pytree — bit-identical at the parity cells
     pinned by ``tests/test_mesh_fit.py``, and agreeing to fp32
     reduction order (ulps) in general.
+
+    **Interventional data.** ``intervened`` is an (m, d) bool array,
+    True where an intervention set that sample's value of that variable
+    (a knockout screen: each cell's guide names the gene it knocked out).
+    ``V = ~intervened`` is the validity mask, ``S_g`` the samples valid
+    for variable ``g`` and ``S_ij = S_i & S_j``. At each ordering step, on
+    the working data ``X``:
+
+      1. ``mu_g``, ``sigma_g`` are the mean and (ddof=0) standard
+         deviation over ``S_g``, ``z_g = (x_g - mu_g) / sigma_g``, and
+         ``H(x_g)`` comes from ``E_{S_g}[log cosh z_g]`` and
+         ``E_{S_g}[z_g exp(-z_g^2 / 2)]``.
+      2. For each pair, over ``S_ij``: ``a_i`` and ``v_i`` are the mean
+         and variance of ``z_i``, ``k_ij`` the covariance of ``z_i`` and
+         ``z_j``, and ``u_{i<-j} = ((z_i - a_i) - (k_ij / v_j)(z_j - a_j))
+         / sqrt(v_i - k_ij^2 / v_j)``; ``H(r_{i<-j})`` comes from the
+         means of ``log cosh u`` and ``u exp(-u^2 / 2)`` over ``S_ij``.
+      3. The scores are DirectLiNGAM's (``ordering.step_scores``).
+      4. After root ``r`` is chosen, every remaining ``j`` takes
+         ``x_j <- x_j - (Cov_{S_jr}(x_j, x_r) / Var_{S_jr}(x_r)) x_r`` on
+         all samples.
+      5. Pruning: ``B[j, P_j]`` is the OLS with intercept of ``x_j`` on its
+         predecessors over ``S_j``; ``resid_var[j]`` is taken over ``S_j``.
+
+    With no sample intervened this is the observational estimator. It is
+    a different estimator, not a better one everywhere: ``S_ij`` also
+    drops, from the pair ``(i, j)``, the samples in which the regressor
+    ``j`` was knocked out, though they are draws of ``i``'s own equation
+    and carry the knockout's effect on ``i``. On the ``gene-964-int``
+    benchmark screens (4,096 cells x 964 genes, 192 genes knocked out in
+    about 17 cells each; TPU v5e) the masked fit put 1.15-2.84% of the
+    generator's true edges the wrong way round, against 1.02-1.30% for the
+    pooled fit of the same cells, on each of six seeds. The interventional
+    fit runs the local plan only (staged or full
+    compaction, OLS pruning); a mesh partition, ``moment_chunk`` or
+    another pruner raise ``ValueError``. ``None`` (the default) runs the
+    observational program.
     """
-    if config.partition is not None:
+    if intervened is not None:
+        intervened = jnp.asarray(intervened, dtype=bool)
+        _check_intervened(x, intervened, config)
+    elif config.partition is not None:
         from . import sharded
 
         with obs.span("fit.mesh", m=x.shape[0], d=x.shape[1]):
@@ -297,7 +382,7 @@ def fit_fn(x, config: FitConfig = FitConfig()) -> FitResult:
         # Same (op, shape, config) signature as the compile_log.record
         # inside fit_impl, so cost rows join compile events.
         return obs_profile.call(
-            _fit_local, x, config,
+            _fit_local, x, config, intervened,
             op="core.fit", shape=x.shape, config=config,
         )
 
@@ -336,7 +421,7 @@ def _fit_from_stats_local(x, mean, cov, config: FitConfig) -> FitResult:
 
 
 def fit_from_stats(
-    x, mean, cov, config: FitConfig = FitConfig()
+    x, mean, cov, config: FitConfig = FitConfig(), intervened=None
 ) -> FitResult:
     """DirectLiNGAM fit that reuses precomputed sufficient statistics.
 
@@ -360,7 +445,14 @@ def fit_from_stats(
     standardization-dependent); ``config.moment_chunk`` bounds that pass
     at O(chunk) sample slabs. The mesh plan has no from-stats variant —
     partitioned configs are rejected with a pointer to ``fit_fn``.
+    Neither has interventional data: ``intervened`` raises ``ValueError``
+    (one covariance cannot stand for each variable's own samples).
     """
+    if intervened is not None:
+        raise ValueError(
+            "fit_from_stats takes no intervened mask: each variable's "
+            "moments would need its own samples; use fit_fn(x, config, "
+            "intervened=...)")
     if config.partition is not None:
         raise ValueError(
             "fit_from_stats runs the local/vmap plans only; the mesh "

@@ -71,9 +71,16 @@ def _fit_many_jit(xs, config: FitConfig) -> FitResult:
     return jax.vmap(lambda x: fit_impl(x, config))(xs)
 
 
-def fit_many(xs, config: FitConfig = FitConfig()) -> FitResult:
+def fit_many(xs, config: FitConfig = FitConfig(), intervened=None
+             ) -> FitResult:
     """Fit every dataset in ``xs`` (b, m, d); returns a batched FitResult
-    (order: (b, d), adjacency: (b, d, d), resid_var: (b, d))."""
+    (order: (b, d), adjacency: (b, d, d), resid_var: (b, d)). The engine
+    runs observational fits only: ``intervened`` raises ``ValueError``."""
+    if intervened is not None:
+        raise ValueError(
+            "fit_many takes no intervened mask: the vmap engine runs the "
+            "observational fit only; fit each interventional dataset "
+            "through api.fit_fn(x, config, intervened=...)")
     return obs_profile.call(
         _fit_many_jit, xs, config,
         op="batched.fit_many", shape=xs.shape, config=config,

@@ -5,6 +5,8 @@ Public API:
     model = DirectLiNGAM(backend="pallas").fit(X)
     model.causal_order_   # (d,) — position p holds the variable index
     model.adjacency_      # (d, d) — B[i, j] = direct effect of x_j on x_i
+    # knockout screen: M[s, g] True where sample s's guide knocked out g
+    model = DirectLiNGAM(compaction="staged").fit(X, intervened=M)
 
 The algorithm is unchanged from the sequential version (identical
 identifiability guarantees, as the paper stresses); only the execution is
@@ -63,10 +65,17 @@ class DirectLiNGAM:
             tune=self.tune,
         )
 
-    def fit(self, x) -> "DirectLiNGAM":
+    def fit(self, x, intervened=None) -> "DirectLiNGAM":
+        """Fit (m, d) data. ``intervened`` (optional, (m, d) bool) marks
+        the samples in which an intervention set the variable (a
+        knockout screen's targets); each variable's moments and
+        regression then leave those samples out. That is a different
+        estimator from the pooled fit, and on the benchmark's knockout
+        screens it oriented fewer true edges than pooling; see
+        ``api.fit_fn``."""
         with obs.span("lingam.fit"):
             x = jnp.asarray(x, dtype=jnp.float32)
-            result = api.fit_fn(x, self.to_config())
+            result = api.fit_fn(x, self.to_config(), intervened=intervened)
             self.result_ = result
             with obs.span("lingam.fetch"):
                 self.causal_order_ = np.asarray(result.order)

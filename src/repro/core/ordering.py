@@ -22,6 +22,10 @@ the sample/pair reductions are carried out, abstracted behind a small
     is both the single-device plan and the **vmap** plan: the batched
     engine (:mod:`repro.core.batched`) maps the very same step over a
     leading dataset axis.
+  * :class:`MaskedReducer` — the local plan on interventional data:
+    each variable's reductions run over the samples it was not
+    intervened on, each pair's over the samples valid for both (the
+    masked moment kernel).
   * ``MeshReducer`` (:mod:`repro.core.sharded`) — the **mesh** plan:
     samples sharded over data axes (``psum`` reductions), the (i, j)
     pair space tiled over a model axis (row-tile moments +
@@ -79,7 +83,15 @@ class LocalReducer:
       * ``standardize(x) -> (x_std, c, mu, var)`` — delegates to the
         shared :func:`step_standardize` (a plan may override it to fuse
         the correlation into the raw-X matmul, cf.
-        ``fused_standardize``).
+        ``fused_standardize``). ``c`` is whatever ``moment_rows`` takes
+        as its pair statistics (the masked plan: each pair's residual
+        map).
+      * ``residual_coef(x, xr, root, mu, var) -> (width,)`` — each
+        column's regression coefficient on the root column ``xr``
+        (delegates to the shared :func:`step_residual_coef`).
+      * ``take_columns(idx)`` — the reducer for the columns ``idx`` of
+        the working data after a compaction gather (itself here; the
+        masked plan gathers its per-column validity).
       * ``col_multiple`` — physical column widths must be multiples of
         this (1 here; the pair-axis size on a mesh), honoured by the
         staged-compaction driver when it shrinks the buffer.
@@ -135,6 +147,138 @@ class LocalReducer:
 
     def col_moments(self, x_std):
         return measures.nonlinear_moments(x_std, axis=0)
+
+    def residual_coef(self, x, xr, root, mu, var):
+        return step_residual_coef(x, xr, root, mu, var, self)
+
+    def take_columns(self, idx):
+        return self
+
+
+class MaskedReducer(LocalReducer):
+    """The local plan on interventional data: a validity mask per
+    (sample, variable).
+
+    ``valid[s, g]`` is 1.0 where sample ``s`` counts for variable ``g``
+    and 0.0 where an intervention set ``g`` in that sample; ``S_g`` is the
+    set of samples valid for ``g`` and ``S_ij = S_i & S_j``. Each of
+    ``g``'s reductions runs over ``S_g`` (standardization, the column
+    moments), each pair's over ``S_ij`` (the residual's affine
+    standardization and its moments, the residual-update coefficient).
+    With every sample valid this is the estimator of
+    :class:`LocalReducer`.
+
+    The reducer holds the validity of the working columns and the pair
+    counts ``|S_ij|``; staged compaction gathers them with the data
+    (:meth:`take_columns`). No moment chunking: each step's moments are
+    one call of the masked kernel.
+    """
+
+    def __init__(self, valid, n_pair=None, *, backend=None, interpret=None,
+                 tune="cache"):
+        super().__init__(backend=backend, interpret=interpret, tune=tune)
+        self.valid = valid
+        self.count = jnp.maximum(jnp.sum(valid, axis=0), 1.0)
+        if n_pair is None:
+            with jax.named_scope("lingam.mask_stats"):
+                n_pair = jnp.maximum(_gram(valid, valid), 1.0)
+        self.n_pair = n_pair
+
+    def take_columns(self, idx):
+        return MaskedReducer(
+            jnp.take(self.valid, idx, axis=1),
+            self.n_pair[idx][:, idx],
+            backend=self.backend, interpret=self.interpret, tune=self.tune,
+        )
+
+    def mean_over_samples(self, v):
+        return jnp.sum(v * self.valid, axis=0) / self.count
+
+    def standardize(self, x):
+        """``(z, (c, alpha, gamma), mu, var)``: ``z`` standardized over
+        each column's valid samples, and each pair's residual map."""
+        mu = self.mean_over_samples(x)
+        xc = x - mu[None, :]
+        var = jnp.maximum(self.mean_over_samples(xc * xc), EPS)
+        z = xc * jax.lax.rsqrt(var)[None, :]
+        with jax.named_scope("lingam.mask_stats"):
+            stats = masked_pair_stats(z, self.valid, self.n_pair)
+        return z, stats, mu, var
+
+    def moment_rows(self, z, stats):
+        return ops.pairwise_moments_masked(
+            z, self.valid, *stats, self.n_pair, backend=self.backend,
+            interpret=self.interpret, tune_mode=self.tune,
+        )
+
+    def col_moments(self, z):
+        logcosh, uexp = measures.nonlinear_terms(z)
+        return self.mean_over_samples(logcosh), self.mean_over_samples(uexp)
+
+    def residual_coef(self, x, xr, root, mu, var):
+        """``Cov(x_j, x_r) / Var(x_r)`` over each ``S_jr``, two-pass."""
+        w = self.valid * self.valid[:, root][:, None]
+        n = self.n_pair[:, root]
+        mean_r = jnp.sum(w * xr[:, None], axis=0) / n
+        mean_j = jnp.sum(w * x, axis=0) / n
+        dr = xr[:, None] - mean_r[None, :]
+        cov = jnp.sum(w * (x - mean_j[None, :]) * dr, axis=0) / n
+        var_r = jnp.maximum(jnp.sum(w * dr * dr, axis=0) / n, EPS)
+        return cov / var_r
+
+
+def _gram(a, b):
+    """``a^T b`` at full fp32 precision: on a TPU the default precision
+    rounds the products to bfloat16, which the pair statistics below
+    cannot afford (their differences cancel)."""
+    return jax.lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def masked_pair_stats(z, valid, n_pair):
+    """Each pair's residual map on interventional data.
+
+    Over ``S_ij`` (``n_pair[i, j]`` samples): ``a_i`` and ``v_i`` are the
+    mean and variance of ``z_i``, ``k_ij`` the covariance of ``z_i`` and
+    ``z_j``, all from the matmuls ``(V z)^T V``, ``(V z^2)^T V`` and
+    ``(V z)^T (V z)`` (``V = valid``). The residual of ``z_i`` on ``z_j``,
+    standardized over ``S_ij``, is
+
+        u = ((z_i - a_i) - c (z_j - a_j)) / sqrt(v_i - k c),  c = k / v_j,
+
+    returned as ``(c, alpha, gamma)`` with ``u = alpha (z_i - c z_j) +
+    gamma``: ``alpha = (v_i - k c)^(-1/2)``, ``gamma = alpha (c a_j - a_i)``.
+    """
+    vz = valid * z
+    s_z = _gram(vz, valid)                # [i, j] = sum over S_ij of z_i
+    s_zz = _gram(vz * z, valid)
+    s_zizj = _gram(vz, vz)
+    a = s_z / n_pair                      # [i, j] = a_i; a.T = a_j
+    var_i = jnp.maximum(s_zz / n_pair - a * a, EPS)
+    k = s_zizj / n_pair - a * a.T
+    c = k / var_i.T
+    alpha = jax.lax.rsqrt(jnp.maximum(var_i - k * c, EPS))
+    gamma = alpha * (c * a.T - a)
+    return c, alpha, gamma
+
+
+def step_residual_coef(x, xr, root, mu, var, reducer):
+    """``Cov(x_j, x_root) / Var(x_root)`` for every working column.
+
+    mu/var come from standardize — no extra sample reduction (on a
+    mesh: no extra psum round) for the root's moments. The covariance
+    is two-pass (centered product) for the same fp32-cancellation
+    reason as step_standardize; pad rows are masked after centering.
+    """
+    mean_r = mu[root]
+    var_r = var[root]
+    cov = reducer.mean_over_samples(
+        reducer.mask_rows((x - mu[None, :]) * (xr - mean_r)[:, None])
+    )
+    return cov / var_r
 
 
 def step_standardize(x, reducer):
@@ -224,18 +368,9 @@ def ordering_step(x, active, reducer):
 
     # Residualize every other active column on the root column of the
     # *unstandardized* working data (matches the sequential reference).
-    # mu/var come from standardize — no extra sample reduction (on a
-    # mesh: no extra psum round) for the root's moments. The covariance
-    # is two-pass (centered product) for the same fp32-cancellation
-    # reason as step_standardize; pad rows are masked after centering.
     with jax.named_scope("lingam.residual"):
         xr = x[:, root]
-        mean_r = mu[root]
-        var_r = var[root]
-        cov = reducer.mean_over_samples(
-            reducer.mask_rows((x - mu[None, :]) * (xr - mean_r)[:, None])
-        )
-        coef = cov / var_r  # (width,)
+        coef = reducer.residual_coef(x, xr, root, mu, var)  # (width,)
         update = jnp.where(
             active & (jnp.arange(x.shape[1]) != root), coef, 0.0)
         x_new = x - xr[:, None] * update[None, :]
@@ -340,10 +475,9 @@ def compact_order_impl(x, reducer, *, d=None, frac=0.25, min_stage=8):
     labels = jnp.arange(width, dtype=jnp.int32)  # current column -> original
     active = jnp.arange(width) < d
     parts = []
-    body = _scan_body(reducer)
     for w_logical, n_steps in _stage_schedule(d, frac, min_stage):
         (x, active), roots = jax.lax.scan(
-            body, (x, active), None, length=n_steps
+            _scan_body(reducer), (x, active), None, length=n_steps
         )
         keep = w_logical - n_steps
         with jax.named_scope("lingam.compact"):
@@ -356,6 +490,7 @@ def compact_order_impl(x, reducer, *, d=None, frac=0.25, min_stage=8):
             idx = jnp.argsort(jnp.where(active, jnp.arange(width), width))
             idx = idx[:keep_pad]
             x = jnp.take(x, idx, axis=1)
+            reducer = reducer.take_columns(idx)
             labels = labels[idx]
             if keep_pad != keep:
                 colmask = jnp.arange(keep_pad) < keep

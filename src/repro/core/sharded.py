@@ -164,6 +164,12 @@ class MeshReducer:
         cm2 = jax.lax.psum(jnp.sum(uexp, axis=0), self.sample_axes) / self.m
         return cm1, cm2
 
+    def residual_coef(self, x, xr, root, mu, var):
+        return ordering.step_residual_coef(x, xr, root, mu, var, self)
+
+    def take_columns(self, idx):
+        return self
+
     def gather_samples(self, x_local):
         """Reassemble the full (m_pad, width) array from sample shards
         (exact: a gather moves bits, it does not reduce)."""

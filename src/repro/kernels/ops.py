@@ -9,6 +9,10 @@
   * ``"pallas"``  — the Pallas TPU kernel (interpreted automatically when
                     no accelerator backs the process).
 
+``pairwise_moments_masked(...)`` is the interventional variant (each
+pair's moments over the samples valid for both variables), with the same
+three backends.
+
 All backends return (M1, M2) of shape (d, d) fp32 with identical values up
 to fp32 accumulation tolerance; tests/test_kernels.py sweeps shapes/dtypes
 against the oracle.
@@ -43,34 +47,80 @@ def _round_up(x: int, k: int) -> int:
     return ((x + k - 1) // k) * k
 
 
-def pairwise_moments_blocked(x_std, c, block: int = 64):
+def pairwise_moments_blocked(x_std, c, block: int = 64, masked=None):
     """Row-blocked jnp implementation: O(block * d * m) peak memory.
 
     Scans over blocks of ``i`` rows; within a block the (block, d, m)
     residual tensor is formed and reduced. XLA fuses the nonlinearities
     into the reduction, so HBM traffic stays ~(d/block) * read(X).
+
+    ``masked`` = ``(valid, alpha, gamma, n_pair)`` computes the masked
+    moments of :func:`pairwise_moments_masked`: ``u = alpha (x_i - c x_j)
+    + gamma``, each pair-sample weighed by both variables' validity, the
+    sums divided by ``n_pair``.
     """
     m, d = x_std.shape
     block = min(block, _round_up(d, 8))  # don't pad tiny d up to a block
     d_pad = _round_up(d, block)
-    xt = jnp.pad(x_std.T.astype(jnp.float32), ((0, d_pad - d), (0, 0)))
-    c_pad = jnp.pad(c.astype(jnp.float32), ((0, d_pad - d), (0, d_pad - d)))
-    inv_std = jax.lax.rsqrt(jnp.maximum(1.0 - c_pad * c_pad, ref.EPS))
+
+    def pad_rows(a):
+        return jnp.pad(a.astype(jnp.float32), ((0, d_pad - d), (0, 0)))
+
+    def pad_pairs(a):
+        return jnp.pad(a.astype(jnp.float32),
+                       ((0, d_pad - d), (0, d_pad - d)))
+
+    xt = pad_rows(x_std.T)
+    c_pad = pad_pairs(c)
+    if masked is None:
+        inv_std = jax.lax.rsqrt(jnp.maximum(1.0 - c_pad * c_pad, ref.EPS))
+    else:
+        valid, alpha, gamma, n_pair = masked
+        vt = pad_rows(valid.T)
+        inv_std = pad_pairs(alpha)
+        gamma = pad_pairs(gamma)
 
     def body(_, idx):
-        xi = jax.lax.dynamic_slice_in_dim(xt, idx * block, block, 0)
-        ci = jax.lax.dynamic_slice_in_dim(c_pad, idx * block, block, 0)
-        inv = jax.lax.dynamic_slice_in_dim(inv_std, idx * block, block, 0)
+        def rows(a):
+            return jax.lax.dynamic_slice_in_dim(a, idx * block, block, 0)
+
+        xi, ci, inv = rows(xt), rows(c_pad), rows(inv_std)
         r = xi[:, None, :] - ci[:, :, None] * xt[None, :, :]
-        logcosh, uexp = _nonlinear_terms(r * inv[:, :, None])
-        m1 = jnp.mean(logcosh, axis=-1)
-        m2 = jnp.mean(uexp, axis=-1)
-        return None, (m1, m2)
+        u = r * inv[:, :, None]
+        if masked is None:
+            logcosh, uexp = _nonlinear_terms(u)
+            return None, (jnp.mean(logcosh, axis=-1), jnp.mean(uexp, axis=-1))
+        logcosh, uexp = _nonlinear_terms(u + rows(gamma)[:, :, None])
+        w = rows(vt)[:, None, :] * vt[None, :, :]
+        return None, (jnp.sum(logcosh * w, axis=-1),
+                      jnp.sum(uexp * w, axis=-1))
 
     _, (m1, m2) = jax.lax.scan(body, None, jnp.arange(d_pad // block))
     m1 = m1.reshape(d_pad, d_pad)[:d, :d]
     m2 = m2.reshape(d_pad, d_pad)[:d, :d]
+    if masked is not None:
+        m1, m2 = m1 / n_pair, m2 / n_pair
     return m1, m2
+
+
+def _pallas_layout(plan, m, d):
+    """A pair-tile Pallas plan's layout at (m, d): its column block, and
+    the pads that lay an (m, d) array out variables-major and a (d, d)
+    array on the same variable extent, both fp32 with zero padding."""
+    d_pad, bj = tune.padded_extent(d, plan.bi, plan.bj)
+    m_pad = _round_up(m, plan.bm)
+
+    def pad_t(a):
+        return jnp.pad(
+            a.T.astype(jnp.float32), ((0, d_pad - d), (0, m_pad - m))
+        )
+
+    def pad_pairs(a):
+        return jnp.pad(
+            a.astype(jnp.float32), ((0, d_pad - d), (0, d_pad - d))
+        )
+
+    return bj, pad_t, pad_pairs
 
 
 @functools.partial(
@@ -116,21 +166,63 @@ def pairwise_moments(
         return pairwise_moments_blocked(x_std, c, block=block or plan.block)
     if plan.backend == "pallas":
         interpret = tune.resolve_interpret(interpret)
-        bi, bm = plan.bi, plan.bm
-        d_pad, bj = tune.padded_extent(d, bi, plan.bj)
-        m_pad = _round_up(m, bm)
-        xt = jnp.pad(
-            x_std.T.astype(jnp.float32), ((0, d_pad - d), (0, m_pad - m))
-        )
-        c_pad = jnp.pad(
-            c.astype(jnp.float32), ((0, d_pad - d), (0, d_pad - d))
-        )
+        bj, pad_t, pad_pairs = _pallas_layout(plan, m, d)
         m1, m2 = pairwise_stats.pairwise_moments_pallas(
-            xt, c_pad, m_total=m, d_total=d, bi=bi, bj=bj, bm=bm,
-            interpret=interpret,
+            pad_t(x_std), pad_pairs(c), m_total=m, d_total=d, bi=plan.bi,
+            bj=bj, bm=plan.bm, interpret=interpret,
         )
         return m1[:d, :d], m2[:d, :d]
     raise ValueError(f"unknown backend: {plan.backend}")
+
+
+@functools.partial(
+    jax.jit, static_argnames=("backend", "interpret", "tune_mode")
+)
+def pairwise_moments_masked(
+    x_std,
+    valid,
+    c,
+    alpha,
+    gamma,
+    n_pair,
+    *,
+    backend: str = None,
+    interpret: bool = None,
+    tune_mode: str = _DEFAULT_TUNE,
+):
+    """Masked pairwise residual moments, for interventional data.
+
+    x_std: (m, d) standardized; valid: (m, d) 1.0 where the sample counts
+    for the variable, else 0.0; c, alpha, gamma: (d, d), each pair's
+    residual map ``u_ij = alpha_ij (x_i - c_ij x_j) + gamma_ij``; n_pair:
+    (d, d) the count of samples valid for both. Returns (M1, M2), (d, d)
+    fp32: the means of ``log cosh u`` and ``u exp(-u^2/2)`` over each
+    pair's common valid samples. ``backend="ref"`` computes them from
+    ``x_std`` and ``valid`` alone, by their definition
+    (:func:`repro.kernels.ref.pairwise_moments_masked_ref`).
+    """
+    m, d = x_std.shape
+    if backend == "ref":
+        return ref.pairwise_moments_masked_ref(x_std, valid)
+    plan = tune.dispatch(
+        "pairwise_moments_masked", (m, d), str(x_std.dtype), backend,
+        mode=tune_mode,
+    )
+    if plan.backend == "blocked":
+        return pairwise_moments_blocked(
+            x_std, c, block=plan.block,
+            masked=(valid, alpha, gamma, n_pair),
+        )
+    if plan.backend != "pallas":
+        raise ValueError(f"unknown backend: {plan.backend}")
+    interpret = tune.resolve_interpret(interpret)
+    bj, pad_t, pad_pairs = _pallas_layout(plan, m, d)
+    s1, s2 = pairwise_stats.pairwise_moments_masked_pallas(
+        pad_t(x_std), pad_t(valid), pad_pairs(c), pad_pairs(alpha),
+        pad_pairs(gamma), m_total=m, d_total=d, bi=plan.bi, bj=bj,
+        bm=plan.bm, interpret=interpret,
+    )
+    return s1[:d, :d] / n_pair, s2[:d, :d] / n_pair
 
 
 def pairwise_moment_sums_rows(

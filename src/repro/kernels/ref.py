@@ -59,3 +59,39 @@ def pairwise_moments_ref(x_std, c):
     m1 = jnp.mean(logcosh, axis=-1)
     m2 = jnp.mean(uexp, axis=-1)
     return m1, m2
+
+
+def pairwise_moments_masked_ref(z, valid):
+    """Oracle of the masked moments, from their definition.
+
+    Args:
+      z:     (m, d) standardized samples.
+      valid: (m, d) 1.0 where the sample counts for the variable, else 0.
+    Returns:
+      (M1, M2): each (d, d), fp32. For each pair, over ``S_ij``, the
+      samples valid for both: ``a_i``, ``v_i`` the mean and variance of
+      ``z_i``, ``k_ij`` the covariance, ``c = k_ij / v_j`` and
+      ``u = ((z_i - a_i) - c (z_j - a_j)) / sqrt(v_i - k_ij c)``;
+      ``M1 = E_{S_ij}[log cosh u]``, ``M2 = E_{S_ij}[u exp(-u^2 / 2)]``.
+      Materializes (d, d, m): small problems only.
+    """
+    zt = z.T.astype(jnp.float32)                     # (d, m)
+    vt = valid.T.astype(jnp.float32)
+    w = vt[:, None, :] * vt[None, :, :]              # (d, d, m)
+    n = jnp.maximum(jnp.sum(w, axis=-1), 1.0)
+
+    def mean(t):
+        return jnp.sum(w * t, axis=-1) / n
+
+    a_i = mean(zt[:, None, :])                       # [i, j]: E_{S_ij} z_i
+    a_j = mean(zt[None, :, :])
+    d_i = zt[:, None, :] - a_i[:, :, None]
+    d_j = zt[None, :, :] - a_j[:, :, None]
+    v_i = mean(d_i * d_i)
+    v_j = jnp.maximum(mean(d_j * d_j), EPS)
+    k = mean(d_i * d_j)
+    beta = k / v_j
+    r = d_i - beta[:, :, None] * d_j
+    u = r * jax.lax.rsqrt(jnp.maximum(v_i - k * beta, EPS))[:, :, None]
+    logcosh, uexp = nonlinear_terms(u)
+    return mean(logcosh), mean(uexp)

@@ -78,18 +78,27 @@ def _trace_state_clean() -> bool:
     return jax.core.trace_ctx.is_top_level()
 
 
-def vmem_bytes(bi: int, bj: int, bm: int) -> int:
+def vmem_bytes(bi: int, bj: int, bm: int, masked: bool = False) -> int:
     """fp32 VMEM working set of one (BI, BJ, BM) grid cell of the moment
     kernels (``pairwise_stats.moment_sums``): the double-buffered input
     blocks (x_i, x_j, correlations) and output blocks, and the residual
     and integrand tensors of the (BI, BJ, ACCUM_CHUNK) sample chunks the
     kernel forms one by one. Mosaic keeps about ten such chunk tensors
     live (compiled for v5e: 24.3 MB at (32, 128, 2048), 36.0 MB at
-    (64, 128, 1024)); the model counts twelve."""
+    (64, 128, 1024)); the model counts twelve.
+
+    The masked kernel (``masked``) also streams the two validity blocks
+    (as large as x_i's and x_j's) and two more per-pair blocks (its
+    ``alpha`` and ``gamma``), and forms each chunk's weights and offset
+    sum: two more chunk tensors."""
+    pair_blocks = 3 if masked else 1
+    sample_blocks = 2 if masked else 1
+    chunk_tensors = 14 if masked else 12
     return 4 * (
-        2 * (bi * bm + bj * bm + bi * bj)   # inputs, double-buffered
+        2 * (sample_blocks * (bi * bm + bj * bm)
+             + pair_blocks * bi * bj)       # inputs, double-buffered
         + 2 * 2 * bi * bj                   # two outputs, double-buffered
-        + 12 * bi * bj * ACCUM_CHUNK        # live chunk tensors
+        + chunk_tensors * bi * bj * ACCUM_CHUNK  # live chunk tensors
     )
 
 
@@ -263,6 +272,23 @@ def _pair_pallas_heuristic(shape, chunk=None) -> Plan:
     )
 
 
+def _masked_pallas_heuristic(shape, chunk=None) -> Plan:
+    m, d = shape
+    bi, bj, bm = heuristic_pair_blocks(d, m)
+    return Plan(
+        op="pairwise_moments_masked", variant="pallas-pair-tile-masked",
+        backend="pallas", bi=bi, bj=bj, bm=bm,
+    )
+
+
+def _masked_blocked_heuristic(shape, chunk=None) -> Plan:
+    m, d = shape
+    return Plan(
+        op="pairwise_moments_masked", variant="blocked-rows-masked",
+        backend="blocked", block=min(64, _round_up(max(d, 1), _SUBLANE)),
+    )
+
+
 def _pair_blocked_heuristic(shape, chunk=None) -> Plan:
     m, d = shape
     block = min(64, _round_up(max(d, 1), _SUBLANE))
@@ -378,6 +404,24 @@ register(KernelVariant(
     ),
     validate=_validate_fixed,
 ))
+# The masked pair tile (interventional fits) takes its heuristic blocks
+# only: nothing is tuned for it yet.
+register(KernelVariant(
+    name="pallas-pair-tile-masked",
+    op="pairwise_moments_masked",
+    backend="pallas",
+    constraints=Constraints(mesh_compatible=False, tunable=()),
+    heuristic=_masked_pallas_heuristic,
+    validate=_validate_fixed,
+))
+register(KernelVariant(
+    name="blocked-rows-masked",
+    op="pairwise_moments_masked",
+    backend="blocked",
+    constraints=Constraints(mesh_compatible=False, tunable=()),
+    heuristic=_masked_blocked_heuristic,
+    validate=_validate_fixed,
+))
 register(KernelVariant(
     name="pallas-row-tile",
     op="pairwise_moment_sums_rows",
@@ -448,8 +492,8 @@ def dispatch(
 
     Args:
       op:     registered op name ("pairwise_moments",
-              "pairwise_moment_sums_rows", "pairwise_moment_sums_chunked",
-              "fused_moment_sums").
+              "pairwise_moments_masked", "pairwise_moment_sums_rows",
+              "pairwise_moment_sums_chunked", "fused_moment_sums").
       shape:  static dispatch shape — (m, d) for the pair ops,
               (tile, d, m) for the row/fused ops. Called at trace time,
               where these are Python ints.
@@ -486,7 +530,8 @@ def dispatch(
     obs_profile.note_plan(
         op, shape, variant=plan.variant, source=plan.source,
         vmem_model_bytes=(
-            vmem_bytes(plan.bi, plan.bj, plan.bm)
+            vmem_bytes(plan.bi, plan.bj, plan.bm,
+                       masked=op == "pairwise_moments_masked")
             if plan.backend == "pallas" and plan.bi else 0
         ),
     )

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import api
 from repro.kernels import ops
+from repro.kernels.tune import registry
 
 HBM_BYTES = 16 * 2**30
 
@@ -80,6 +81,14 @@ _KERNELS = {
         ),
         [(32_768, 964), (964, 964)],
     ),
+    # The masked pair tile of the interventional fit at gene-964's
+    # benchmark width: data, validity, the pair maps and counts.
+    "masked_4096x964": (
+        lambda z, v, c, a, g, n: ops.pairwise_moments_masked(
+            z, v, c, a, g, n, **_PALLAS
+        ),
+        [(4096, 964), (4096, 964)] + [(964, 964)] * 4,
+    ),
     # Fused standardize + moments (Partition(fused_standardize=True)).
     "fused_d964": (
         lambda x, mu, rstd, c: ops.fused_moment_rows(
@@ -122,3 +131,36 @@ def test_fit_kernel_keeps_its_name_under_the_moments_scope(one_chip):
         assert line.lstrip().startswith("%pairwise_moments_pallas"), line
         (op_name,) = re.findall(r'op_name="([^"]*)"', line)
         assert "/lingam.moments/" in op_name, op_name
+
+
+def test_masked_kernel_plan_counts_its_mask_blocks_within_vmem():
+    """The masked kernel's heuristic blocks at 4,096 x 964, with its two
+    validity blocks and two more per-pair blocks counted, fit the VMEM
+    budget that the compile above holds them to."""
+    plan = registry.dispatch_heuristic(
+        "pairwise_moments_masked", (4096, 964), backend="pallas")
+    masked = registry.vmem_bytes(plan.bi, plan.bj, plan.bm, masked=True)
+    assert registry.vmem_bytes(plan.bi, plan.bj, plan.bm) < masked
+    assert masked <= registry._VMEM_BUDGET
+
+
+def test_masked_staged_fit_compiles_for_v5e(one_chip):
+    """The interventional fit of the benchmark's cell (4,096 x 964,
+    staged, one masked kernel a stage) as one program that fits the chip:
+    the masked kernel under ``lingam.moments``, its pair statistics under
+    ``lingam.mask_stats``."""
+    cfg = api.FitConfig(compaction="staged", **_PALLAS)
+    mask = jax.ShapeDtypeStruct((4096, 964), jnp.bool_, sharding=one_chip)
+    compiled = api._fit_local.lower(
+        _sds(one_chip, 4096, 964), cfg, mask).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) > 1
+    for line in calls:
+        assert line.lstrip().startswith("%pairwise_moments_masked"), line
+        (op_name,) = re.findall(r'op_name="([^"]*)"', line)
+        assert "/lingam.moments/" in op_name, op_name
+    assert "/lingam.mask_stats/" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
