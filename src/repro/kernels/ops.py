@@ -222,7 +222,14 @@ def pairwise_moments_masked(
         pad_pairs(gamma), m_total=m, d_total=d, bi=plan.bi, bj=bj,
         bm=plan.bm, interpret=interpret,
     )
-    return s1[:d, :d] / n_pair, s2[:d, :d] / n_pair
+    # The kernel's first sum is of ``log cosh u + log 2``: a pair's
+    # weights sum to its count of common valid samples, which is
+    # ``n_pair`` wherever that count is not 0, so log 2 comes off the
+    # mean. A pair with no common sample has sums of exactly 0 (every
+    # weighed term is positive) and keeps its moments of 0.
+    s1, s2 = s1[:d, :d], s2[:d, :d]
+    m1 = jnp.where(s1 == 0.0, 0.0, s1 / n_pair - pairwise_stats.LOG2)
+    return m1, s2 / n_pair
 
 
 def pairwise_moment_sums_rows(

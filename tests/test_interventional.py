@@ -103,8 +103,10 @@ def _masked_inputs(m, d, seed=0):
     return z, valid, stats, reducer.n_pair
 
 
-# m = 300 fills no sample block; d = 129 spans two 128-lane column blocks.
-SHAPES = [(300, 13), (512, 24), (256, 129)]
+# m = 300 fills no sample block; d = 129 spans two 128-lane column blocks;
+# m = 64 and 200 leave the one 256-sample block less than a 128-sample
+# chunk, or a chunk and a part, of samples.
+SHAPES = [(300, 13), (512, 24), (256, 129), (64, 13), (200, 13)]
 
 
 def _offdiag_close(got, want, atol):
@@ -137,6 +139,25 @@ def test_masked_moments_match_their_definition(m, d):
     want = ref.pairwise_moments_masked_ref(z, valid)
     got = ops.pairwise_moments_masked(z, valid, *stats, n_pair,
                                       backend="pallas", interpret=True)
+    _offdiag_close(got, want, atol=1e-5)
+
+
+def test_pair_without_a_common_sample_keeps_zero_moments():
+    """The kernel's first sum carries log 2 a weighed sample, which the
+    wrapper takes off each pair's mean; a pair that shares no valid
+    sample has no mean, and keeps the oracle's 0 (its count clamps to 1)."""
+    z, valid, _, _ = _masked_inputs(300, 13)
+    half = np.arange(300) < 150
+    valid = valid.at[:, 0].set(jnp.where(half, valid[:, 0], 0.0))
+    valid = valid.at[:, 1].set(jnp.where(half, 0.0, valid[:, 1]))
+    reducer = ordering.MaskedReducer(valid)
+    zm, stats, _, _ = reducer.standardize(z)
+    assert float(reducer.n_pair[0, 1]) == 1.0
+    got = ops.pairwise_moments_masked(zm, valid, *stats, reducer.n_pair,
+                                      backend="pallas", interpret=True)
+    want = ref.pairwise_moments_masked_ref(zm, valid)
+    for g in got:
+        assert float(g[0, 1]) == 0.0 and float(g[1, 0]) == 0.0
     _offdiag_close(got, want, atol=1e-5)
 
 

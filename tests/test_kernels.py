@@ -5,7 +5,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.pairwise_stats import pairwise_moments_pallas
+from repro.kernels.pairwise_stats import (
+    pairwise_moments_masked_pallas,
+    pairwise_moments_pallas,
+)
 
 RNG = np.random.default_rng(42)
 
@@ -13,6 +16,19 @@ RNG = np.random.default_rng(42)
 def _make(m, d, dtype=np.float32, dist="laplace"):
     if dist == "laplace":
         x = RNG.laplace(size=(m, d))
+    elif dist == "tiny":
+        # Ten samples a column at full scale, in pairs of opposite sign so
+        # that the mean stays near 0: the rest standardize below 1e-3, the
+        # pairs' correlations are near 0, and so is most |u|.
+        x = RNG.laplace(size=(m, d)) * 1e-4
+        rows = np.argsort(RNG.uniform(size=(m, d)), axis=0)[:10]
+        a = RNG.laplace(size=(5, d))
+        x[rows, np.arange(d)] = np.concatenate([a, -a])
+    elif dist == "heavy":
+        # One outlier a column standardizes to about sqrt(m) (> 20 at
+        # m = 500): log cosh at |u| > 20, where exp(-2|u|) underflows.
+        x = RNG.laplace(size=(m, d))
+        x[RNG.integers(m, size=d), np.arange(d)] = 300.0
     else:
         x = RNG.uniform(size=(m, d))
     x = x.astype(dtype)
@@ -66,10 +82,14 @@ def test_pallas_block_shape_sweep(bi, bj, bm):
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
-@pytest.mark.parametrize("dist", ["laplace", "uniform"])
+@pytest.mark.parametrize("dist", ["laplace", "uniform", "tiny", "heavy"])
 def test_pallas_dtype_dist_sweep(dtype, dist):
     m, d = 500, 12
     xs, c = _make(m, d, dtype=dtype, dist=dist)
+    if dist == "tiny":
+        assert np.median(np.abs(xs)) < 1e-3
+    if dist == "heavy":
+        assert np.min(np.max(np.abs(xs), axis=0)) > 20.0
     m1r, m2r = ref.pairwise_moments_ref(xs, c)
     m1p, m2p = ops.pairwise_moments(xs, c, backend="pallas", interpret=True)
     _offdiag_close(m1r, m1p, d, atol=2e-6)
@@ -92,20 +112,26 @@ def test_bf16_input_upcast():
 
 # The shared moment sums against the oracle, through both kernels that
 # run them. (m, d, bi, bm): samples that fill the sample blocks exactly
-# (no mask is emitted), a ragged last block after three full ones, and row
+# (no mask is emitted), a ragged last block after three full ones, one
+# block of 256 that holds fewer than a chunk of samples (a chunk with
+# none: its log 2 count clamps at 0) or a chunk and a part, and row
 # blocks of 8, 32 and 128 one variable past a lane tile (the pair-tile
 # grid stops at the last row block that holds a variable).
 _MOMENT_CASES = {
     "filled": (512, 20, 8, 256),
     "ragged": (3 * 256 + 37, 20, 8, 256),
+    "ragged_m64": (64, 20, 8, 256),
+    "ragged_m200": (200, 20, 8, 256),
     "bi8_d129": (300, 129, 8, 128),
     "bi32_d129": (300, 129, 32, 128),
     "bi128_d129": (300, 129, 128, 128),
 }
 
 
-def _kernel_masks(fn, *args):
-    """Number of selects anywhere in the kernel body: the sample masks."""
+def _kernel_primitives(fn, *args):
+    """Count of each primitive anywhere in fn's one ``pallas_call`` body."""
+    import collections
+
     import jax
 
     def kernels(jaxpr):
@@ -115,15 +141,15 @@ def _kernel_masks(fn, *args):
             for sub in _subjaxprs(eqn.params):
                 yield from kernels(sub)
 
-    def selects(jaxpr):
-        return sum(
-            (eqn.primitive.name == "select_n")
-            + sum(selects(sub) for sub in _subjaxprs(eqn.params))
-            for eqn in jaxpr.eqns
-        )
+    def count(jaxpr, counts):
+        for eqn in jaxpr.eqns:
+            counts[eqn.primitive.name] += 1
+            for sub in _subjaxprs(eqn.params):
+                count(sub, counts)
+        return counts
 
     (body,) = kernels(jax.make_jaxpr(fn)(*args).jaxpr)
-    return selects(body)
+    return count(body, collections.Counter())
 
 
 @pytest.mark.parametrize("kernel", ["pair", "rows"])
@@ -164,8 +190,28 @@ def test_moment_kernels_match_oracle(kernel, case):
                 np.asarray(got) * mask, np.asarray(want)[start:] * m * mask,
                 atol=2e-6 * m, rtol=0,
             )
-    # a mask only where the last sample block is ragged
-    assert (_kernel_masks(fn, xs, c) > 0) == (m % bm != 0)
+    # a mask (select) only where the last sample block is ragged
+    masks = _kernel_primitives(fn, xs, c)["select_n"]
+    assert (masks > 0) == (m % bm != 0)
+
+
+@pytest.mark.parametrize("kernel", ["pair", "masked"])
+def test_kernel_body_takes_log_not_log1p(kernel):
+    """The kernel bodies take log cosh's log on the EUP (``log``), not the
+    VALU-expanded ``log1p`` (see ``pairwise_stats``'s module docstring)."""
+    import functools
+
+    d, m = 16, 256
+    x_t = jnp.zeros((d, m), jnp.float32)
+    pairs = jnp.zeros((d, d), jnp.float32)
+    blocks = dict(m_total=200, d_total=d, bi=8, bj=d, bm=m)
+    if kernel == "pair":
+        fn, args = pairwise_moments_pallas, (x_t, pairs)
+    else:
+        fn, args = pairwise_moments_masked_pallas, (x_t, x_t) + (pairs,) * 3
+    prims = _kernel_primitives(functools.partial(fn, **blocks), *args)
+    assert prims["log"] > 0
+    assert prims["log1p"] == 0
 
 
 # Padding edges: tile / d / m just above and below the block multiples
@@ -180,6 +226,8 @@ _EDGE_CELLS = [
     (9, 15, 255),   # tile just above bi, m just below 2*128
     (8, 17, 257),   # d one past 2*8, m one past 2*128
     (16, 16, 128),  # exact multiples (no-padding control cell)
+    (8, 16, 64),    # m under one chunk (fused: a 256 block of 64)
+    (9, 17, 200),   # m a chunk and a part (fused: a 256 block of 200)
 ]
 
 
