@@ -3,9 +3,9 @@
   PYTHONPATH=src python -m benchmarks.run [--full] [--only NAME]
 
 Prints ``name,metric=value,...`` CSV lines per benchmark and mirrors
-every benchmark's results to a repo-root ``BENCH_<artifact>.json`` file
+every benchmark's results to a repo-root ``BENCH_<name>.json`` file
 — the machine-readable perf-trajectory artifacts CI and future sessions
-diff (the kernel-autotuning sweep lands as ``BENCH_kernels.json``).
+diff.
 ``--out`` optionally also writes one aggregate JSON.
 """
 
@@ -31,7 +31,6 @@ from benchmarks import (  # noqa: E402
     bench_speedup,
     bench_stocks,
     bench_stream,
-    bench_tune,
 )
 
 BENCHES = {
@@ -43,15 +42,10 @@ BENCHES = {
     "bootstrap": bench_bootstrap.run,      # loop vs vmap-batched engine
     "sharded": bench_sharded.run,          # mesh-plan sweep vs 1-dev oracle
     "stream": bench_stream.run,            # rolling-window vs from-scratch
-    "tune": bench_tune.run,                # heuristic vs tuned kernel plans
     "infer": bench_infer.run,              # batched queries vs per-query loop
     "drift": bench_drift.run,              # drift detection + refit savings
     "profile": bench_profile.run,          # cost accounting + roofline rows
 }
-
-# Benchmark name -> repo-root artifact stem (BENCH_<stem>.json).
-ARTIFACTS = {name: name for name in BENCHES}
-ARTIFACTS["tune"] = "kernels"
 
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
@@ -173,7 +167,7 @@ def main() -> None:
         payload = res if isinstance(res, dict) else {"rows": res}
         if args.profile and name in profiles:
             payload = stamp_rows(dict(payload), profiles[name])
-        write_artifact(ARTIFACTS[name], payload)
+        write_artifact(name, payload)
 
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)

@@ -88,19 +88,6 @@ def phase(name, fn, *args):
     return figures
 
 
-def dispatched_variants(op):
-    """Kernel variants the dispatcher chose for ``op`` in this phase
-    (telemetry counts each decision at trace time)."""
-    from repro.obs import metrics
-
-    keys = metrics.snapshot()["counters"]
-    return sorted(
-        {k.split('variant="')[1].split('"')[0]
-         for k in keys if k.startswith("kernels.dispatch")
-         and f'op="{op}"' in k}
-    )
-
-
 def check_fit(res, d, what):
     """A DirectLiNGAM result: the order is a permutation and the
     adjacency finite and strictly lower-triangular in that order."""
@@ -180,7 +167,6 @@ def kernel_phase(x):
     check(np.isfinite(diff) and diff <= MOMENT_TOL,
           f"Pallas moments differ from the reference by {diff}")
     return {"backend": backend, "interpret": interpret,
-            "variants": dispatched_variants("pairwise_moments"),
             "max_abs_diff": f"{diff:.3e}", "tol": MOMENT_TOL}
 
 
@@ -191,7 +177,6 @@ def gene_fit_phase(x):
     jax.block_until_ready(res)
     check_fit(res, x.shape[1], "gene-964 staged fit")
     return {"compaction": "staged",
-            "variants": dispatched_variants("pairwise_moments"),
             "n_edges": int((np.asarray(res.adjacency) != 0).sum())}
 
 
@@ -220,8 +205,7 @@ def backends_phase(x, true_order):
         xj, api.FitConfig(compaction="staged", backend="blocked")
     )
     default = api.fit_fn(xj, api.FitConfig(compaction="staged"))
-    return {"variants": dispatched_variants("pairwise_moments"),
-            **compare_plans(blocked, default, true_order, "backends")}
+    return compare_plans(blocked, default, true_order, "backends")
 
 
 def serving_phase(requests, stream_rows, d):
@@ -264,9 +248,7 @@ def serving_phase(requests, stream_rows, d):
           "RCA query: bad answer")
     return {"fit_requests": len(done), "stream_chunks": session.n_chunks,
             "stream_refits": session.n_refits, "deltas": len(deltas),
-            "flush_errors": 0, "queries": 2,
-            "variants": dispatched_variants("pairwise_moments")
-            + dispatched_variants("pairwise_moment_sums_chunked")}
+            "flush_errors": 0, "queries": 2}
 
 
 def var_phase(series):
@@ -277,8 +259,7 @@ def var_phase(series):
     check_fit(res, series.shape[1], "VarLiNGAM B0")
     check(all(np.isfinite(t).all() for t in model.adjacency_matrices_),
           "VarLiNGAM: non-finite lag matrices")
-    return {"lags": 1, "n_matrices": len(model.adjacency_matrices_),
-            "variants": dispatched_variants("pairwise_moments")}
+    return {"lags": 1, "n_matrices": len(model.adjacency_matrices_)}
 
 
 def mesh_phase(x, true_order):
@@ -297,11 +278,7 @@ def mesh_phase(x, true_order):
     )
     n_devices = len(mesh.adjacency.sharding.device_set)
     check(n_devices == 4, f"mesh output spans {n_devices} devices, not 4")
-    row_variants = dispatched_variants("pairwise_moment_sums_rows")
-    check(row_variants == ["pallas-row-tile"],
-          f"mesh row tiles dispatched to {row_variants}")
     return {"mesh": "data2xmodel2", "output_devices": n_devices,
-            "row_variants": row_variants,
             **compare_plans(local, mesh, true_order, "mesh vs local")}
 
 
@@ -338,8 +315,8 @@ def main():
     from repro.launch.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache()}", flush=True)
-    # Telemetry records host spans and the dispatcher's decisions at
-    # trace time; it stages nothing into the compiled programs.
+    # Telemetry records host spans; it stages nothing into the compiled
+    # programs.
     obs.enable()
     gene = WORKLOADS["lingam-gene-964"]
     stocks = WORKLOADS["varlingam-stocks-487"]

@@ -12,8 +12,7 @@ With ``--telemetry`` the run also drives the serving engine (a fit
 micro-batch, a streaming session through refit flushes, and a causal
 query) with the observability layer on (:mod:`repro.obs`), then prints
 the span tree, the metrics snapshot, and the compile-event log —
-covering kernel dispatch -> ordering -> pruning -> serve flush ->
-query.
+covering ordering -> pruning -> serve flush -> query.
 
 With ``--profile`` it runs the performance-accounting layer
 (:mod:`repro.obs.profile`): a profiled fit inside a correlated
@@ -116,7 +115,7 @@ def main():
 
 
 def telemetry_demo(out_dir=None):
-    """Drive dispatch -> ordering -> pruning -> serve flush -> query
+    """Drive ordering -> pruning -> serve flush -> query
     with telemetry on; print the span tree + metrics + compile log.
 
     ``out_dir`` additionally writes ``metrics_snapshot.json``. For the

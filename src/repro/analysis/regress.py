@@ -45,7 +45,6 @@ STEM_TO_BENCH = {
     "bootstrap": "bootstrap",
     "sharded": "sharded",
     "stream": "stream",
-    "kernels": "tune",
     "infer": "infer",
     "drift": "drift",
     "profile": "profile",

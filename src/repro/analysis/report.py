@@ -137,13 +137,12 @@ def live_attribution(
 def kernel_variant_rows(
     m: int, d: int, *, repeats: int = 2, include_pallas: bool = True,
 ) -> List[Dict[str, Any]]:
-    """Per-kernel-variant utilization at one (m, d): each registered
+    """Per-backend utilization at one (m, d): each
     ``pairwise_moments`` backend timed through the profiled path (the
     Pallas variant runs interpreted on cpu — slow but measured)."""
     import numpy as np
 
     from repro.kernels import ops
-    from repro.kernels.tune import registry
     from repro.obs import profile
 
     profile.enable()
@@ -155,7 +154,6 @@ def kernel_variant_rows(
     backends = ["blocked"] + (["pallas"] if include_pallas else [])
     rows: List[Dict[str, Any]] = []
     for backend in backends:
-        variant = registry.get_variant("pairwise_moments", backend).name
         op = f"report.kernel.{backend}"
         for _ in range(repeats):
             profile.call(
@@ -165,7 +163,7 @@ def kernel_variant_rows(
         rec = profile.get(op, (m, d))
         if rec is None:
             continue
-        row = _record_row(variant, rec)
+        row = _record_row(backend, rec)
         row["variant"] = row.pop("stage")
         row["backend"] = backend
         # The analytic model next to the measured numbers: how far the

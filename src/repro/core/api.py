@@ -144,16 +144,10 @@ class FitConfig:
     rolling-window refits set this to the stream chunk size. The mesh
     plan chunks through ``Partition.chunk`` instead and ignores it.
 
-    ``backend=None`` lets the kernel registry pick (pallas on
-    accelerators, blocked elsewhere) and ``interpret=None`` resolves to
-    interpret-only-when-no-accelerator. ``tune`` selects how block
-    shapes/variants are decided (:mod:`repro.kernels.tune`):
-    ``"off"`` — deterministic heuristic, no tuning-table reads (the
-    offline mode); ``"cache"`` (default) — tuned plans from the
-    persistent table, heuristic fallback, never measures; ``"auto"`` —
-    timed search on a table miss, persisted to the user overlay. Tuned
-    and heuristic plans are bit-identical in output (the dispatch
-    parity contract), so ``tune`` never changes results — only speed.
+    ``backend=None`` takes the platform's (pallas on accelerators,
+    blocked elsewhere) and ``interpret=None`` resolves to
+    interpret-only-when-no-accelerator. Kernel blocks follow from the
+    shapes (:mod:`repro.kernels.tune.registry`).
     """
 
     backend: Optional[str] = None
@@ -166,16 +160,11 @@ class FitConfig:
     min_stage: int = 8
     partition: Optional[Partition] = None
     moment_chunk: Optional[int] = None
-    tune: str = "cache"
 
     def __post_init__(self):
         if isinstance(self.prune_kwargs, dict):
             object.__setattr__(
                 self, "prune_kwargs", tuple(sorted(self.prune_kwargs.items()))
-            )
-        if self.tune not in ("off", "cache", "auto"):
-            raise ValueError(
-                f"tune must be 'off', 'cache', or 'auto', got {self.tune!r}"
             )
         if self.moment_chunk is not None:
             if self.backend not in (None, "blocked", "pallas"):
@@ -216,14 +205,12 @@ def _order_for_config(x, config: FitConfig, valid=None):
             backend=config.backend,
             interpret=config.interpret,
             moment_chunk=config.moment_chunk,
-            tune=config.tune,
         )
     else:
         reducer = ordering.MaskedReducer(
             valid,
             backend=config.backend,
             interpret=config.interpret,
-            tune=config.tune,
         )
     if config.compaction == "none":
         return ordering.masked_order_impl(x, reducer)

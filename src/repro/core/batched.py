@@ -114,11 +114,8 @@ def fit_many_from_stats(
 
 def warmup_fit_many(shape, config: FitConfig = FitConfig(), *, batch: int = 1):
     """Prime the vmap plan for datasets of ``shape`` before traffic
-    arrives: one zeros-fit traces + compiles ``fit_many`` (and, through
-    dispatch at trace time, freezes the kernel block plans currently in
-    the tuning table). The serving engine's ``warmup`` calls this after
-    resolving/measuring plans so first requests pay neither search nor
-    compile."""
+    arrives: one zeros-fit traces + compiles ``fit_many``. The serving
+    engine's ``warmup`` calls this so first requests pay no compile."""
     m, d = shape
     xs = jnp.zeros((batch, m, d), jnp.float32)
     jax.block_until_ready(fit_many(xs, config).order)

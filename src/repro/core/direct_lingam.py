@@ -45,7 +45,6 @@ class DirectLiNGAM:
     prune_kwargs: dict = dataclasses.field(default_factory=dict)
     compaction: str = "none"
     partition: Optional[api.Partition] = None
-    tune: str = "cache"
 
     causal_order_: Optional[np.ndarray] = None
     adjacency_: Optional[np.ndarray] = None
@@ -62,7 +61,6 @@ class DirectLiNGAM:
             prune_kwargs=dict(self.prune_kwargs),
             compaction=self.compaction,
             partition=self.partition,
-            tune=self.tune,
         )
 
     def fit(self, x, intervened=None) -> "DirectLiNGAM":

@@ -104,7 +104,6 @@ class LocalReducer:
         backend: str = None,
         interpret: bool = None,
         moment_chunk=None,
-        tune: str = "cache",
     ):
         self.backend = backend
         self.interpret = interpret
@@ -114,9 +113,6 @@ class LocalReducer:
         # streaming plan's rolling-window refits run with chunk-bounded
         # memory. None keeps the classic whole-slab backends.
         self.moment_chunk = moment_chunk
-        # Dispatch mode for the block-shape/variant decisions
-        # (repro.kernels.tune): "off" | "cache" | "auto".
-        self.tune = tune
 
     def mean_over_samples(self, v):
         return jnp.mean(v, axis=0)
@@ -135,11 +131,9 @@ class LocalReducer:
             return ops.pairwise_moments_chunked(
                 x_std, c, chunk=self.moment_chunk,
                 backend=self.backend, interpret=self.interpret,
-                tune_mode=self.tune,
             )
         return ops.pairwise_moments(
             x_std, c, backend=self.backend, interpret=self.interpret,
-            tune_mode=self.tune,
         )
 
     def gather_rows(self, rows):
@@ -174,9 +168,8 @@ class MaskedReducer(LocalReducer):
     one call of the masked kernel.
     """
 
-    def __init__(self, valid, n_pair=None, *, backend=None, interpret=None,
-                 tune="cache"):
-        super().__init__(backend=backend, interpret=interpret, tune=tune)
+    def __init__(self, valid, n_pair=None, *, backend=None, interpret=None):
+        super().__init__(backend=backend, interpret=interpret)
         self.valid = valid
         self.count = jnp.maximum(jnp.sum(valid, axis=0), 1.0)
         if n_pair is None:
@@ -188,7 +181,7 @@ class MaskedReducer(LocalReducer):
         return MaskedReducer(
             jnp.take(self.valid, idx, axis=1),
             self.n_pair[idx][:, idx],
-            backend=self.backend, interpret=self.interpret, tune=self.tune,
+            backend=self.backend, interpret=self.interpret,
         )
 
     def mean_over_samples(self, v):
@@ -208,7 +201,7 @@ class MaskedReducer(LocalReducer):
     def moment_rows(self, z, stats):
         return ops.pairwise_moments_masked(
             z, self.valid, *stats, self.n_pair, backend=self.backend,
-            interpret=self.interpret, tune_mode=self.tune,
+            interpret=self.interpret,
         )
 
     def col_moments(self, z):

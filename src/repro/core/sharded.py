@@ -81,7 +81,6 @@ class MeshReducer:
         backend: str = None,
         interpret: bool = None,
         fused_standardize: bool = False,
-        tune: str = "cache",
     ):
         self.m = m
         self.sample_axes = tuple(sample_axes)
@@ -92,10 +91,6 @@ class MeshReducer:
         self.backend = backend
         self.interpret = interpret
         self.fused_standardize = fused_standardize
-        # Block-shape dispatch mode for the row-tile moment kernel
-        # (repro.kernels.tune); the tuned row-tile sizes under shard_map
-        # come from here.
-        self.tune = tune
 
         # Which local rows are real samples: rows are distributed evenly
         # over the sample shards (this shard's block starts at
@@ -145,7 +140,6 @@ class MeshReducer:
         s1, s2 = ops.pairwise_moment_sums_rows(
             x_std, c, row_start, tile,
             chunk=self.chunk, backend=self.backend, interpret=self.interpret,
-            tune_mode=self.tune,
         )
         s1 = jax.lax.psum(s1, self.sample_axes) / self.m
         s2 = jax.lax.psum(s2, self.sample_axes) / self.m
@@ -331,7 +325,6 @@ def _build_sharded_fit(m: int, d: int, config: FitConfig):
             chunk=part.chunk, backend=config.backend,
             interpret=config.interpret,
             fused_standardize=part.fused_standardize,
-            tune=config.tune,
         )
         order = _order_sharded(x_local, d, config, reducer)
         # The ~4% tail: bit-exact on reassembled data, or fully sharded.
@@ -387,7 +380,6 @@ def make_sharded_causal_order(
     backend: str = None,
     interpret: bool = None,
     fused_standardize: bool = False,
-    tune: str = "cache",
 ):
     """Build a jit-able sharded ordering fn for global data of shape (m, d).
 
@@ -415,7 +407,7 @@ def make_sharded_causal_order(
             m=m, m_local=m_local, axis_sizes=axis_sizes,
             sample_axes=sample_axes, pair_axis=pair_axis, chunk=chunk,
             backend=backend, interpret=interpret,
-            fused_standardize=fused_standardize, tune=tune,
+            fused_standardize=fused_standardize,
         )
         return ordering.masked_order_impl(x_local, reducer, d=d)
 

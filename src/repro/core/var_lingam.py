@@ -71,7 +71,6 @@ class VarLiNGAM:
     prune_threshold: float = 0.0
     compaction: str = "none"
     partition: Optional[api.Partition] = None
-    tune: str = "cache"
 
     causal_order_: Optional[np.ndarray] = None
     adjacency_matrices_: Optional[List[np.ndarray]] = None  # [theta_0..k]
@@ -87,7 +86,6 @@ class VarLiNGAM:
             prune_threshold=self.prune_threshold,
             compaction=self.compaction,
             partition=self.partition,
-            tune=self.tune,
         )
 
     def fit(self, x) -> "VarLiNGAM":

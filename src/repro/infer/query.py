@@ -201,11 +201,8 @@ class QueryEngine:
     per (kind, shape) and never again.
     """
 
-    def __init__(self, *, batch_size: int = 8,
-                 backend: Optional[str] = None, tune: str = "cache"):
+    def __init__(self, *, batch_size: int = 8):
         self.batch_size = batch_size
-        self.backend = backend
-        self.tune = tune
 
     def _bucket(self, n: int) -> int:
         return batched.pow2_bucket(n, self.batch_size)
@@ -290,11 +287,10 @@ class QueryEngine:
         gs, adj, order = self._stack_graphs(part)
         rows = np.stack([q.rows for q in part])  # (b, n, d)
         _, n, d = rows.shape
-        # Heavy reduction: the per-program row slab is the kernel
-        # dispatcher's tuned sample block for this (n, d) bucket, under
-        # the engine's backend/tune mode — padded (zero rows, trimmed
-        # below) so ragged tails reuse a bounded set of compiles.
-        slab = rca_lib._sample_slab(n, d, self.backend, self.tune, None)
+        # Heavy reduction: the per-program row slab is RCA's default
+        # sample slab — padded (zero rows, trimmed below) so ragged
+        # tails reuse a bounded set of compiles.
+        slab = rca_lib.SAMPLE_SLAB
         targets = jnp.asarray(
             [0 if q.target is None else int(q.target) for q in part],
             jnp.int32,

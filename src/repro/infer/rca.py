@@ -18,9 +18,8 @@ sample is then linear algebra, not search:
 
 Everything is batched over samples (plain matmuls) and jit/vmap-clean;
 :func:`attribute` is the host-facing entry, and for wide row batches it
-bounds device memory by slabbing the sample axis with the kernel
-dispatcher's tuned sample block (:func:`repro.kernels.tune.dispatch`)
-— the same decision point the moment kernels use.
+bounds device memory by slabbing the sample axis (:data:`SAMPLE_SLAB`
+rows unless the caller gives a chunk).
 """
 
 from __future__ import annotations
@@ -35,6 +34,9 @@ import numpy as np
 from repro.core import api
 
 _EPS = 1e-12
+#: Default sample slab of the noise pass, in rows (shared with the query
+#: engine's RCA buckets).
+SAMPLE_SLAB = 512
 
 
 def noise_terms_impl(adjacency, rows, mean):
@@ -85,21 +87,6 @@ class RCAResult:
         return [(int(j), float(z[j])) for j in idx]
 
 
-def _sample_slab(n: int, d: int, backend, tune: str, chunk) -> int:
-    """Tuned sample-slab size for the noise pass: the dispatcher's
-    ``bm`` block for this (n, d) bucket, i.e. the same measured
-    decision the chunked moment kernels use; falls back to the full
-    batch when the table offers nothing smaller. Shared with the query
-    engine's RCA buckets."""
-    from repro.kernels import tune as ktune
-
-    plan = ktune.dispatch(
-        "pairwise_moment_sums_chunked", (n, d),
-        backend=backend, mode=tune, chunk=chunk,
-    )
-    return int(plan.bm) if plan.bm else n
-
-
 def _pad_rows(block: np.ndarray, slab: int, axis: int = 0) -> np.ndarray:
     """Zero-pad a slab along the sample axis to a bounded shape set.
 
@@ -128,8 +115,6 @@ def attribute(
     mean=None,
     target: Optional[int] = None,
     chunk: Optional[int] = None,
-    backend: Optional[str] = None,
-    tune: str = "cache",
 ) -> RCAResult:
     """Root-cause attribution of ``rows`` under a fitted graph.
 
@@ -140,10 +125,8 @@ def attribute(
               centered data).
       target: optional variable index; when given, the exact additive
               contribution split toward that variable is returned too.
-      chunk:  bound on the sample slab per device pass; None asks the
-              kernel dispatcher for this shape's tuned block.
-      tune:   dispatcher mode for the slab decision ("off"/"cache"/
-              "auto" — see :mod:`repro.kernels.tune`).
+      chunk:  bound on the sample slab per device pass; None takes
+              :data:`SAMPLE_SLAB`.
     """
     rows = np.asarray(rows, np.float32)
     if rows.ndim == 1:
@@ -153,7 +136,7 @@ def attribute(
         jnp.zeros((d,), jnp.float32) if mean is None
         else jnp.asarray(mean, jnp.float32)
     )
-    slab = chunk or _sample_slab(n, d, backend, tune, chunk)
+    slab = chunk or SAMPLE_SLAB
     tgt = jnp.int32(0 if target is None else int(target))
     scores_parts, contrib_parts = [], []
     for start in range(0, n, slab):

@@ -17,20 +17,17 @@ All backends return (M1, M2) of shape (d, d) fp32 with identical values up
 to fp32 accumulation tolerance; tests/test_kernels.py sweeps shapes/dtypes
 against the oracle.
 
-Every block-shape/variant decision in this module goes through the
-autotuning dispatcher (:func:`repro.kernels.tune.dispatch`): ``backend``
-``None`` lets the registry pick (pallas on accelerators, blocked
-elsewhere), ``interpret`` ``None`` resolves to interpret-only-on-CPU,
-``tune`` selects the dispatch mode (``"off"`` heuristic / ``"cache"`` /
-``"auto"``), and ``plan`` pins an explicit
-:class:`~repro.kernels.tune.registry.Plan` (the autotuner measuring a
-candidate). Tuned and heuristic plans produce bit-identical moments —
-see the parity contract on :mod:`repro.kernels.tune.registry`.
+Block shapes come from the shape rules in
+:mod:`repro.kernels.tune.registry`: ``backend`` ``None`` takes the
+platform's (pallas on accelerators, blocked elsewhere), ``interpret``
+``None`` resolves to interpret-only-on-CPU. The Pallas entries take a
+static ``blocks=(bi, bj, bm)`` in place of the rule's, for tests that pin
+a tiling; any blocks give bit-identical moments (the parity contract on
+:mod:`repro.kernels.tune.registry`).
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -39,8 +36,6 @@ import jax.numpy as jnp
 from . import pairwise_stats, ref
 from .nonlinearity import nonlinear_terms as _nonlinear_terms  # noqa: F401
 from .tune import registry as tune
-
-_DEFAULT_TUNE = "cache"
 
 
 def _round_up(x: int, k: int) -> int:
@@ -103,12 +98,14 @@ def pairwise_moments_blocked(x_std, c, block: int = 64, masked=None):
     return m1, m2
 
 
-def _pallas_layout(plan, m, d):
-    """A pair-tile Pallas plan's layout at (m, d): its column block, and
-    the pads that lay an (m, d) array out variables-major and a (d, d)
-    array on the same variable extent, both fp32 with zero padding."""
-    d_pad, bj = tune.padded_extent(d, plan.bi, plan.bj)
-    m_pad = _round_up(m, plan.bm)
+def _pallas_layout(blocks, m, d):
+    """A pair-tile Pallas plan's layout at (m, d): its blocks with the
+    column block the TPU accepts, and the pads that lay an (m, d) array
+    out variables-major and a (d, d) array on the same variable extent,
+    both fp32 with zero padding."""
+    bi, bj, bm = blocks
+    d_pad, bj = tune.padded_extent(d, bi, bj)
+    m_pad = _round_up(m, bm)
 
     def pad_t(a):
         return jnp.pad(
@@ -120,12 +117,11 @@ def _pallas_layout(plan, m, d):
             a.astype(jnp.float32), ((0, d_pad - d), (0, d_pad - d))
         )
 
-    return bj, pad_t, pad_pairs
+    return (bi, bj, bm), pad_t, pad_pairs
 
 
 @functools.partial(
-    jax.jit, static_argnames=("backend", "interpret", "block", "tune_mode",
-                              "plan")
+    jax.jit, static_argnames=("backend", "interpret", "blocks")
 )
 def pairwise_moments(
     x_std,
@@ -133,9 +129,7 @@ def pairwise_moments(
     *,
     backend: str = None,
     interpret: bool = None,
-    block: int = None,
-    tune_mode: str = _DEFAULT_TUNE,
-    plan: tune.Plan = None,
+    blocks: tuple = None,
 ):
     """Dispatching wrapper. x_std: (m, d) standardized; c: (d, d).
 
@@ -148,36 +142,26 @@ def pairwise_moments(
     if x_std.ndim == 3:
         return jax.vmap(
             lambda xb, cb: pairwise_moments(
-                xb, cb, backend=backend, interpret=interpret, block=block,
-                tune_mode=tune_mode, plan=plan,
+                xb, cb, backend=backend, interpret=interpret, blocks=blocks,
             )
         )(x_std, c)
     m, d = x_std.shape
+    backend = tune.resolve_backend(backend)
     if backend == "ref":
         return ref.pairwise_moments_ref(x_std, c)
-    if plan is None:
-        plan = tune.dispatch(
-            "pairwise_moments", (m, d), str(x_std.dtype), backend,
-            mode=tune_mode,
-        )
-    if plan.backend == "ref":
-        return ref.pairwise_moments_ref(x_std, c)
-    if plan.backend == "blocked":
-        return pairwise_moments_blocked(x_std, c, block=block or plan.block)
-    if plan.backend == "pallas":
-        interpret = tune.resolve_interpret(interpret)
-        bj, pad_t, pad_pairs = _pallas_layout(plan, m, d)
-        m1, m2 = pairwise_stats.pairwise_moments_pallas(
-            pad_t(x_std), pad_pairs(c), m_total=m, d_total=d, bi=plan.bi,
-            bj=bj, bm=plan.bm, interpret=interpret,
-        )
-        return m1[:d, :d], m2[:d, :d]
-    raise ValueError(f"unknown backend: {plan.backend}")
+    if backend == "blocked":
+        return pairwise_moments_blocked(x_std, c)
+    (bi, bj, bm), pad_t, pad_pairs = _pallas_layout(
+        blocks or tune.heuristic_pair_blocks(d, m), m, d
+    )
+    m1, m2 = pairwise_stats.pairwise_moments_pallas(
+        pad_t(x_std), pad_pairs(c), m_total=m, d_total=d, bi=bi, bj=bj,
+        bm=bm, interpret=tune.resolve_interpret(interpret),
+    )
+    return m1[:d, :d], m2[:d, :d]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("backend", "interpret", "tune_mode")
-)
+@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
 def pairwise_moments_masked(
     x_std,
     valid,
@@ -188,7 +172,6 @@ def pairwise_moments_masked(
     *,
     backend: str = None,
     interpret: bool = None,
-    tune_mode: str = _DEFAULT_TUNE,
 ):
     """Masked pairwise residual moments, for interventional data.
 
@@ -199,28 +182,24 @@ def pairwise_moments_masked(
     fp32: the means of ``log cosh u`` and ``u exp(-u^2/2)`` over each
     pair's common valid samples. ``backend="ref"`` computes them from
     ``x_std`` and ``valid`` alone, by their definition
-    (:func:`repro.kernels.ref.pairwise_moments_masked_ref`).
+    (:func:`repro.kernels.ref.pairwise_moments_masked_ref`). The Pallas
+    kernel takes the pair tile's blocks.
     """
     m, d = x_std.shape
+    backend = tune.resolve_backend(backend)
     if backend == "ref":
         return ref.pairwise_moments_masked_ref(x_std, valid)
-    plan = tune.dispatch(
-        "pairwise_moments_masked", (m, d), str(x_std.dtype), backend,
-        mode=tune_mode,
-    )
-    if plan.backend == "blocked":
+    if backend == "blocked":
         return pairwise_moments_blocked(
-            x_std, c, block=plan.block,
-            masked=(valid, alpha, gamma, n_pair),
+            x_std, c, masked=(valid, alpha, gamma, n_pair),
         )
-    if plan.backend != "pallas":
-        raise ValueError(f"unknown backend: {plan.backend}")
-    interpret = tune.resolve_interpret(interpret)
-    bj, pad_t, pad_pairs = _pallas_layout(plan, m, d)
+    (bi, bj, bm), pad_t, pad_pairs = _pallas_layout(
+        tune.heuristic_pair_blocks(d, m), m, d
+    )
     s1, s2 = pairwise_stats.pairwise_moments_masked_pallas(
         pad_t(x_std), pad_t(valid), pad_pairs(c), pad_pairs(alpha),
-        pad_pairs(gamma), m_total=m, d_total=d, bi=plan.bi, bj=bj,
-        bm=plan.bm, interpret=interpret,
+        pad_pairs(gamma), m_total=m, d_total=d, bi=bi, bj=bj, bm=bm,
+        interpret=tune.resolve_interpret(interpret),
     )
     # The kernel's first sum is of ``log cosh u + log 2``: a pair's
     # weights sum to its count of common valid samples, which is
@@ -241,8 +220,7 @@ def pairwise_moment_sums_rows(
     chunk: int = 512,
     backend: str = None,
     interpret: bool = None,
-    tune_mode: str = _DEFAULT_TUNE,
-    plan: tune.Plan = None,
+    blocks: tuple = None,
 ):
     """Pairwise residual moment *sums* for the i-row tile
     ``[row_start, row_start + tile)`` against all columns — the
@@ -263,26 +241,23 @@ def pairwise_moment_sums_rows(
       ``blocked`` scans over sample chunks (pure jnp); ``pallas`` runs
       the paper's kernel on the local slab (row-tile variant) — the
       kernel composed with ``shard_map`` is the full multi-pod
-      configuration. Row-tile block shapes come from the dispatcher
-      (``Partition.chunk`` bounds the sample block); non-divisible
-      extents are zero-padded here and masked in the kernel.
+      configuration. Row-tile blocks come from
+      :func:`repro.kernels.tune.registry.row_tile_blocks`
+      (``Partition.chunk`` sets the sample block when it tiles the
+      slab); non-divisible extents are zero-padded here and masked in
+      the kernel.
     """
     m_local, d = x_std.shape
-    if plan is None:
-        plan = tune.dispatch(
-            "pairwise_moment_sums_rows", (tile, d, m_local),
-            str(x_std.dtype), backend, mode=tune_mode, chunk=chunk,
-        )
-    if plan.backend == "pallas":
-        interpret = tune.resolve_interpret(interpret)
-        bi, bm = plan.bi, plan.bm
+    backend = tune.resolve_backend(backend)
+    if backend == "pallas":
+        bi, bj, bm = blocks or tune.row_tile_blocks(d, m_local, chunk)
         tile_pad = _round_up(tile, bi)
         # Pad variables and samples to block multiples: padded rows and
         # columns are sliced back off below, padded samples are masked
         # or skipped via m_total. The padded rows also hold a tile_pad
         # slice from any valid row_start (<= d - tile), so dynamic_slice
         # never clamps it.
-        d_pad, bj = tune.padded_extent(d + tile_pad - tile, bi, plan.bj)
+        d_pad, bj = tune.padded_extent(d + tile_pad - tile, bi, bj)
         m_pad = _round_up(m_local, bm)
         xt_all = jnp.pad(x_std.T, ((0, d_pad - d), (0, m_pad - m_local)))
         c_full = jnp.pad(c, ((0, d_pad - d), (0, d_pad - d)))
@@ -290,11 +265,11 @@ def pairwise_moment_sums_rows(
         c_rows = jax.lax.dynamic_slice_in_dim(c_full, row_start, tile_pad, 0)
         s1, s2 = pairwise_stats.pairwise_moment_sums_rows(
             xt_rows, xt_all, c_rows, m_total=m_local,
-            bi=bi, bj=bj, bm=bm, interpret=interpret,
+            bi=bi, bj=bj, bm=bm, interpret=tune.resolve_interpret(interpret),
         )
         return s1[:tile, :d], s2[:tile, :d]
-    if plan.backend != "blocked":
-        raise ValueError(f"unknown backend: {plan.backend}")
+    if backend != "blocked":
+        raise ValueError(f"the row tile has no {backend!r} backend")
     xt = x_std.T  # (d, m_local)
     c_rows = jax.lax.dynamic_slice_in_dim(c, row_start, tile, 0)  # (tile, d)
     inv_std = jax.lax.rsqrt(jnp.maximum(1.0 - c_rows * c_rows, ref.EPS))
@@ -333,8 +308,6 @@ def pairwise_moment_sums_chunked(
     chunk: int = 512,
     backend: str = None,
     interpret: bool = None,
-    tune_mode: str = _DEFAULT_TUNE,
-    plan: tune.Plan = None,
 ):
     """Pairwise residual moment *sums* accumulated over sample chunks.
 
@@ -345,8 +318,8 @@ def pairwise_moment_sums_chunked(
     residual intermediate is O(chunk * d^2) instead of O(m * d^2) — a
     rolling window's moments cost one chunk of live memory regardless
     of window length. ``chunk`` is the caller's memory bound and fixes
-    the outer accumulation grouping; the dispatcher tunes the blocks
-    *within* each slab (bit-identical by the parity contract).
+    the outer accumulation grouping; the row tile's shape rule sets the
+    blocks *within* each slab.
 
     Args:
       x_std: (m, d) data standardized by the *window's* statistics.
@@ -359,18 +332,13 @@ def pairwise_moment_sums_chunked(
     """
     m, d = x_std.shape
     chunk = max(1, min(chunk, m))
-    if plan is None:
-        plan = tune.dispatch(
-            "pairwise_moment_sums_chunked", (m, d), str(x_std.dtype),
-            backend, mode=tune_mode, chunk=chunk,
-        )
-    inner_plan = dataclasses.replace(plan, op="pairwise_moment_sums_rows")
-    if plan.backend != "pallas":
+    backend = tune.resolve_backend(backend)
+    if backend != "pallas":
         # The row-tile entry already scans masked (chunk, d) slabs over
         # the full row range for the jnp backend.
         return pairwise_moment_sums_rows(
-            x_std, c, 0, d, chunk=chunk, backend=plan.backend,
-            interpret=interpret, plan=inner_plan,
+            x_std, c, 0, d, chunk=chunk, backend=backend,
+            interpret=interpret,
         )
     # Pallas path: scan the row-tile kernel over chunk slabs; pad the
     # sample axis with zero rows (both integrands vanish at 0).
@@ -382,8 +350,7 @@ def pairwise_moment_sums_chunked(
         s1, s2 = carry
         xs = jax.lax.dynamic_slice_in_dim(x, k * chunk, chunk, 0)
         t1, t2 = pairwise_moment_sums_rows(
-            xs, c, 0, d, chunk=chunk, backend=plan.backend,
-            interpret=interpret, plan=inner_plan,
+            xs, c, 0, d, chunk=chunk, backend=backend, interpret=interpret,
         )
         return (s1 + t1, s2 + t2), None
 
@@ -396,8 +363,7 @@ def pairwise_moment_sums_chunked(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("chunk", "backend", "interpret", "tune_mode",
-                              "plan")
+    jax.jit, static_argnames=("chunk", "backend", "interpret")
 )
 def pairwise_moments_chunked(
     x_std,
@@ -406,8 +372,6 @@ def pairwise_moments_chunked(
     chunk: int = 512,
     backend: str = None,
     interpret: bool = None,
-    tune_mode: str = _DEFAULT_TUNE,
-    plan: tune.Plan = None,
 ):
     """Chunk-accumulated pairwise moment *means*: sums / m.
 
@@ -419,7 +383,6 @@ def pairwise_moments_chunked(
     m, _ = x_std.shape
     s1, s2 = pairwise_moment_sums_chunked(
         x_std, c, chunk=chunk, backend=backend, interpret=interpret,
-        tune_mode=tune_mode, plan=plan,
     )
     inv_m = jnp.float32(1.0 / m)
     return s1 * inv_m, s2 * inv_m
@@ -434,14 +397,14 @@ def fused_moment_rows(
     tile: int,
     *,
     interpret: bool = None,
-    tune_mode: str = _DEFAULT_TUNE,
-    plan: tune.Plan = None,
+    blocks: tuple = None,
 ):
-    """Dispatcher-planned wrapper over the fused standardize+moments
-    kernel (:func:`repro.kernels.fused_stats.fused_moment_sums`).
+    """Wrapper over the fused standardize+moments kernel
+    (:func:`repro.kernels.fused_stats.fused_moment_sums`), blocks from
+    :func:`repro.kernels.tune.registry.fused_blocks`.
 
     Takes *raw* sample-major data plus the per-variable standardization
-    constants, pads every extent to the plan's block multiples (padded
+    constants, pads every extent to the block multiples (padded
     samples are masked in the kernel; padded variables are sliced back
     off), and returns the (tile, d) moment *sums* for rows
     ``[row_start, row_start + tile)``. ``row_start`` is a host int here
@@ -450,17 +413,12 @@ def fused_moment_rows(
     from .fused_stats import fused_moment_sums
 
     m, d = x_raw.shape
-    if plan is None:
-        plan = tune.dispatch(
-            "fused_moment_sums", (tile, d, m), str(x_raw.dtype),
-            "pallas", mode=tune_mode,
-        )
     interpret = tune.resolve_interpret(interpret)
-    bi, bm = plan.bi, plan.bm
+    bi, bj, bm = blocks or tune.fused_blocks(d, m)
     tile_pad = _round_up(tile, bi)
     # The row slice must fit inside the padded variable extent even when
     # the tile straddles the end of the real rows.
-    d_pad, bj = tune.padded_extent(max(d, row_start + tile_pad), bi, plan.bj)
+    d_pad, bj = tune.padded_extent(max(d, row_start + tile_pad), bi, bj)
     m_pad = _round_up(m, bm)
     xt = jnp.pad(x_raw.T, ((0, d_pad - d), (0, m_pad - m)))
     # Per-variable constants as (d_pad, 1) columns: 2-D blocks that

@@ -62,13 +62,13 @@ validity (0 or 1) of both variables at that sample. It streams the two
 validity blocks beside the data blocks and returns sums; the caller
 divides by each pair's count of common valid samples.
 
-Block shapes come from the autotuning dispatcher
-(:mod:`repro.kernels.tune`) through the ops wrappers, which pad every
-extent to them; each block's last dimension is a multiple of 128 or the
-whole padded extent, as the TPU requires. The sample axis accumulates
-in fixed ``ACCUM_CHUNK``-wide sub-chunks, so any
-``bm`` that is a multiple of it produces a bit-identical reduction order
-— tuned and heuristic plans differ only in speed, never in bits.
+Block shapes come from the shape rules in
+:mod:`repro.kernels.tune.registry` through the ops wrappers, which pad
+every extent to them; each block's last dimension is a multiple of 128
+or the whole padded extent, as the TPU requires. The sample axis
+accumulates in fixed ``ACCUM_CHUNK``-wide sub-chunks, so any ``bm`` that
+is a multiple of it produces a bit-identical reduction order — blocks
+change speed, never bits.
 
 Each ``pallas_call`` has a fixed ``name``: the custom call's instruction
 name in the compiled program and in a profiler trace, whatever function

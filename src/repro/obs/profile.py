@@ -188,7 +188,7 @@ def utilization(
 
 
 # ---------------------------------------------------------------------------
-# Analytic cost model (dispatch-time estimates + the test oracle)
+# Analytic cost model (estimates from the shapes + the test oracle)
 # ---------------------------------------------------------------------------
 
 #: Flops per (pair, sample) element of the moment kernels' integrands:
@@ -453,28 +453,6 @@ def call(fn, *args, op: str, shape=None, config=None, **kwargs):
     if rec.temp_bytes:
         metrics.gauge("profile.temp_bytes", rec.temp_bytes, op=op)
     return out
-
-
-def note_plan(op: str, shape, *, variant: str, source: str,
-              vmem_model_bytes: int = 0) -> None:
-    """Record a dispatch decision's analytic cost as gauges.
-
-    Called from ``kernels.tune.registry.dispatch`` (trace time, once per
-    compile): the plan's modelled arithmetic intensity and VMEM working
-    set become queryable next to the measured records, so a plan whose
-    model disagrees with captured ``temp_bytes`` is visible.
-    """
-    if not _ENABLED:
-        return
-    a = analytic_cost(op, shape)
-    if a is not None:
-        metrics.gauge("profile.plan_intensity", a["intensity"],
-                      op=op, variant=variant, source=source)
-        metrics.gauge("profile.plan_flops", a["flops"],
-                      op=op, variant=variant, source=source)
-    if vmem_model_bytes:
-        metrics.gauge("profile.plan_vmem_bytes", vmem_model_bytes,
-                      op=op, variant=variant, source=source)
 
 
 def records() -> List[CostRecord]:

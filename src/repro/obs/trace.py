@@ -12,8 +12,8 @@ arbitrary attributes. They are **jit-safe by construction**: a span is
 pure host bookkeeping — it never stages anything into a traced program,
 so instrumented and uninstrumented runs produce bit-identical results
 and identical compile counts. A span entered while a jax trace is being
-built (e.g. around :func:`repro.kernels.tune.registry.dispatch`, which
-runs at trace time) is tagged ``traced=True``: it measures trace/compile
+built (e.g. the fit's stage spans, which run at trace time) is tagged
+``traced=True``: it measures trace/compile
 construction, fires once per compile, and never re-executes in steady
 state — compile-event accounting, not steady-state latency.
 

@@ -111,10 +111,8 @@ class CausalDiscoveryEngine:
     discipline as the one-shot path. ``post_chunk`` auto-flushes once a
     full micro-batch of sessions is due.
 
-    ``warmup(shapes)`` pre-resolves the kernel block plans (running the
-    autotuner's timed search when the config says ``tune="auto"``) and
-    pre-compiles the fit programs for the expected dataset shapes, so
-    first requests pay neither a plan search nor a compile.
+    ``warmup(shapes)`` pre-compiles the fit programs for the expected
+    dataset shapes, so first requests pay no compile.
     """
 
     def __init__(self, config: Optional[lingam_api.FitConfig] = None,
@@ -129,50 +127,18 @@ class CausalDiscoveryEngine:
         # Bounded: a pathological flush over many sessions cannot grow
         # the error record without limit (drops are counted).
         self.last_flush_errors: obs.BoundedRing = obs.BoundedRing(256)
-        self.queries = query_lib.QueryEngine(
-            batch_size=batch_size,
-            backend=self.config.backend,
-            tune=self.config.tune,
-        )
+        self.queries = query_lib.QueryEngine(batch_size=batch_size)
         if warmup_shapes:
             self.warmup(warmup_shapes)
 
-    def warmup(
-        self,
-        shapes: List[Tuple[int, int]],
-        *,
-        tune_mode: Optional[str] = None,
-        compile: bool = True,
-    ) -> Dict[str, object]:
-        """Pre-resolve kernel plans (and pre-compile the fit programs)
-        for the (m, d) dataset shapes this engine expects.
-
-        With ``tune_mode="auto"`` (or ``FitConfig(tune="auto")``) the
-        block-shape search runs *now*, per shape bucket, and persists to
-        the tuning overlay (``$REPRO_TUNE_CACHE``) — so neither one-shot
-        requests nor streaming refits ever pay a first-request search.
-        Returns the
-        resolved plans keyed by their tuning-table keys.
-        """
-        from repro.kernels.tune import autotune as ktune_autotune
-
-        mode = tune_mode or self.config.tune
-        # The fit path only routes through the chunked op when the
-        # config bounds the moment pass; warm exactly what it will ask.
-        warm_ops = ("pairwise_moments",) if (
-            self.config.moment_chunk is None
-        ) else ("pairwise_moments", "pairwise_moment_sums_chunked")
-        plans = ktune_autotune.warmup_plans(
-            shapes,
-            ops=warm_ops,
-            backend=self.config.backend,
-            mode=mode,
-            chunk=self.config.moment_chunk,
-        )
-        if compile and self.config.partition is None:
+    def warmup(self, shapes: List[Tuple[int, int]]) -> None:
+        """Pre-compile the vmap fit program for each (m, d) dataset shape
+        this engine expects, so first requests pay no compile. Returns
+        None. The mesh plan (``config.partition`` set) compiles on its
+        first request."""
+        if self.config.partition is None:
             for shape in shapes:
                 lingam_batched.warmup_fit_many(shape, self.config)
-        return plans
 
     def _bucket(self, n: int) -> int:
         return lingam_batched.pow2_bucket(n, self.batch_size)

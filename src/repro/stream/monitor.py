@@ -33,9 +33,8 @@ monitoring costs one small jitted transform per chunk and never
 re-reads rows (``tests/test_monitor.py`` pins zero extra data passes).
 The transform is one compiled program per ``(d, lags)`` shape shared
 across every session, with a vmapped batch entry
-(:func:`score_chunks_many`) whose micro-batch bucketing follows the
-kernel dispatcher's tuned sample block
-(:func:`repro.kernels.tune.dispatch`) like the RCA slabs do.
+(:func:`score_chunks_many`) whose micro-batches pad to the size
+:func:`_batch_bucket` gives.
 
 Alerts are :class:`DriftAlert` objects carrying the implicated
 variable, the firing statistic, a kind label, and candidate root
@@ -347,19 +346,18 @@ class GraphHealthMonitor:
         )
 
 
-def _batch_bucket(n: int, d: int) -> int:
-    """Micro-batch bucket for the vmapped scorer: the dispatcher's
-    tuned sample block for this shape family bounds the padded batch
-    (the same measured decision point the RCA slabs consult), rounded
-    to the power-of-two set so steady traffic compiles O(log) shapes."""
+def _batch_bucket(n: int) -> int:
+    """Micro-batch bucket for the vmapped scorer: ``n`` on the blocked
+    backend; elsewhere the next power of two, capped at ``n`` rounded up
+    to 128."""
     from repro.core.batched import pow2_bucket
-    from repro.kernels import tune as ktune
+    from repro.kernels.tune import registry
 
-    plan = ktune.dispatch(
-        "pairwise_moment_sums_chunked", (n, d), mode="cache", chunk=n
-    )
-    cap = int(plan.bm) if plan.bm else max(n, 1)
-    return pow2_bucket(n, max(cap, n, 1))
+    # The values a kernel block dispatcher used to pick; nothing states
+    # why they differ by backend (ROADMAP, Queue 3).
+    if registry.default_backend() == "blocked":
+        return n
+    return pow2_bucket(n, -(-n // 128) * 128)
 
 
 def score_chunks_many(
@@ -372,14 +370,14 @@ def score_chunks_many(
 
     All monitors must share ``(d, lags)`` (one compile per shape
     family; the engine groups sessions the same way it buckets refits).
-    Padding repeats the first entry up to the dispatcher-derived
-    power-of-two bucket, so a burst of concurrent sessions costs one
-    device program instead of a per-session loop.
+    Padding repeats the first entry up to the micro-batch bucket, so a
+    burst of concurrent sessions costs one device program instead of a
+    per-session loop.
     """
     if not monitors:
         return []
     n = len(monitors)
-    bucket = _batch_bucket(n, monitors[0].d)
+    bucket = _batch_bucket(n)
     pad = bucket - n
 
     def stack(xs):

@@ -50,26 +50,6 @@ from . import stats
 _RIDGE = 1e-6
 
 
-def _tuned_moment_chunk(
-    d: int, chunk: int, window_chunks: int, config: api.FitConfig
-) -> int:
-    """Default ordering-moment slab for a rolling window: ask the
-    dispatcher for this window's tuned sample block, bounded by the
-    stream chunk (the session's declared memory budget). With an empty
-    tuning table (or ``tune="off"``) this degrades to the stream chunk
-    exactly — the legacy default."""
-    from repro.kernels import tune as ktune
-
-    plan = ktune.dispatch(
-        "pairwise_moment_sums_chunked",
-        (chunk * window_chunks, d),
-        backend=config.backend,
-        mode=config.tune,
-        chunk=chunk,
-    )
-    return min(chunk, plan.bm) if plan.bm else chunk
-
-
 def lagged_rows(buf: np.ndarray, lags: int) -> np.ndarray:
     """Lag-augmented rows of a contiguous (n, d) block.
 
@@ -204,10 +184,9 @@ class RollingVarLiNGAM:
       lags:          VAR order k.
       config:        the DirectLiNGAM :class:`~repro.core.api.FitConfig`
                      for the residual fit; ``moment_chunk`` defaults to
-                     the dispatcher's tuned sample block for this
-                     window's shape bucket (never above the stream
-                     chunk — that is the session's memory bound), so
-                     the ordering moments accumulate in tuned slabs.
+                     the stream chunk (the session's memory bound), so
+                     the ordering moments accumulate one chunk at a
+                     time.
       reanchor_every: if > 0, rebuild the moment state from the live
                      ring every that-many slides (post window fill) to
                      cap retraction drift on non-stationary streams.
@@ -238,11 +217,7 @@ class RollingVarLiNGAM:
         self.lags = lags
         self.reanchor_every = reanchor_every
         if config.moment_chunk is None:
-            config = dataclasses.replace(
-                config, moment_chunk=_tuned_moment_chunk(
-                    d, chunk, window_chunks, config
-                )
-            )
+            config = dataclasses.replace(config, moment_chunk=chunk)
         self.config = config
         self.ring = ChunkRing(window_chunks)
         self.aug_state = stats.init((lags + 1) * d)

@@ -155,32 +155,25 @@ def _kernel_primitives(fn, *args):
 @pytest.mark.parametrize("kernel", ["pair", "rows"])
 @pytest.mark.parametrize("case", sorted(_MOMENT_CASES))
 def test_moment_kernels_match_oracle(kernel, case):
-    from repro.kernels.tune import Plan
-
     m, d, bi, bm = _MOMENT_CASES[case]
     xs, c = _make(m, d)
     m1r, m2r = ref.pairwise_moments_ref(xs, c)
     bj = 128 if d > 128 else d + (-d) % 8
     if kernel == "pair":
-        plan = Plan(op="pairwise_moments", variant="pallas-pair-tile",
-                    backend="pallas", bi=bi, bj=bj, bm=bm, source="override")
-
         def fn(x, c):
             return ops.pairwise_moments(
-                x, c, backend="pallas", interpret=True, plan=plan)
+                x, c, backend="pallas", interpret=True, blocks=(bi, bj, bm))
 
         m1, m2 = fn(xs, c)
         _offdiag_close(m1r, m1, d, atol=2e-6)
         _offdiag_close(m2r, m2, d, atol=2e-6)
     else:
-        plan = Plan(op="pairwise_moment_sums_rows", variant="pallas-row-tile",
-                    backend="pallas", bi=bi, bj=bj, bm=bm, source="override")
         start, tile = 1, d - 1  # a tile that straddles the row blocks
 
         def fn(x, c):
             return ops.pairwise_moment_sums_rows(
                 x, c, start, tile, backend="pallas", interpret=True,
-                plan=plan)
+                blocks=(bi, bj, bm))
 
         s1, s2 = fn(xs, c)
         assert s1.shape == (tile, d)
@@ -216,7 +209,7 @@ def test_kernel_body_takes_log_not_log1p(kernel):
 
 # Padding edges: tile / d / m just above and below the block multiples
 # (bi=8, bj=8, bm=128/256), pinned against the blocked-oracle sums. The
-# ops wrappers pad to the plan's blocks and mask/slice the excess; these
+# ops wrappers pad to the blocks and mask/slice the excess; these
 # cells would silently corrupt the edge rows/columns if the padding or
 # the m_total mask were off by one.
 _EDGE_CELLS = [
@@ -240,18 +233,12 @@ def _rows_oracle_sums(xs, c, tile):
 
 @pytest.mark.parametrize("tile,d,m", _EDGE_CELLS)
 def test_rows_padding_edges_vs_blocked_oracle(tile, d, m):
-    from repro.kernels.tune import Plan
-
     xs, c = _make(m, d)
     s1r, s2r = _rows_oracle_sums(xs, c, tile)
-    # force a plan whose blocks do NOT divide the shape, so the wrapper
-    # must pad every axis and mask the sample tail
-    plan = Plan(
-        op="pairwise_moment_sums_rows", variant="pallas-row-tile",
-        backend="pallas", bi=8, bj=8, bm=128, source="override",
-    )
+    # force blocks that do NOT divide the shape, so the wrapper must pad
+    # every axis and mask the sample tail
     s1, s2 = ops.pairwise_moment_sums_rows(
-        xs, c, 0, tile, backend="pallas", interpret=True, plan=plan
+        xs, c, 0, tile, backend="pallas", interpret=True, blocks=(8, 8, 128)
     )
     assert s1.shape == (tile, d)
     mask = 1.0 - np.eye(tile, d)
@@ -273,8 +260,6 @@ def test_rows_padding_edges_vs_blocked_oracle(tile, d, m):
 
 @pytest.mark.parametrize("tile,d,m", _EDGE_CELLS)
 def test_fused_padding_edges_vs_blocked_oracle(tile, d, m):
-    from repro.kernels.tune import Plan
-
     x = RNG.laplace(size=(m, d)).astype(np.float32)
     xj = jnp.asarray(x)
     xs = ops.standardize(xj)
@@ -282,12 +267,8 @@ def test_fused_padding_edges_vs_blocked_oracle(tile, d, m):
     s1r, s2r = _rows_oracle_sums(xs, c, tile)
     mu = jnp.mean(xj, axis=0)
     rstd = 1.0 / jnp.maximum(jnp.std(xj, axis=0), 1e-12)
-    plan = Plan(
-        op="fused_moment_sums", variant="pallas-fused",
-        backend="pallas", bi=8, bj=8, bm=256, source="override",
-    )
     s1, s2 = ops.fused_moment_rows(
-        xj, mu, rstd, c, 0, tile, interpret=True, plan=plan
+        xj, mu, rstd, c, 0, tile, interpret=True, blocks=(8, 8, 256)
     )
     assert s1.shape == (tile, d)
     mask = 1.0 - np.eye(tile, d)
@@ -348,7 +329,7 @@ def test_fused_kernel_matches_oracle(dtype):
     )
 
 
-# Every block a Pallas call gets from the dispatcher must tile the way
+# Every block a Pallas call gets from the shape rules must tile the way
 # the TPU requires: last dimension a multiple of 128 or the whole array
 # extent, second-to-last a multiple of 8 or the whole extent. Checked on
 # the traced pallas_call at every d up to 130 (staged compaction shrinks
@@ -419,3 +400,236 @@ def test_pallas_blocks_tile_legally_at_every_width(case):
                 case, d, block, array)
             assert block[-2] % 8 == 0 or block[-2] == array[-2], (
                 case, d, block, array)
+
+
+# ---------------------------------------------------------------------------
+# Block plans: a function of the shapes
+# ---------------------------------------------------------------------------
+
+
+def test_heuristic_matches_legacy_pick_blocks():
+    """The pair-tile rule keeps the old ops._pick_blocks rows and sample
+    blocks; below 128 columns bj is the whole padded column extent, the
+    only sub-lane block the TPU accepts."""
+    from repro.kernels.tune import registry
+
+    legacy = {
+        (300, 4): (8, 8, 256),
+        (300, 16): (8, 16, 256),
+        (600, 64): (8, 64, 512),
+        (600, 128): (8, 128, 512),
+        (5000, 200): (8, 128, 2048),
+    }
+    for (m, d), want in legacy.items():
+        assert registry.heuristic_pair_blocks(d, m) == want, (m, d)
+
+
+def test_default_interpret_tracks_backend():
+    """interpret=None resolves from the detected backend: the Pallas
+    interpreter only when no accelerator backs the process."""
+    import jax
+
+    from repro.kernels.tune import registry
+
+    expect = jax.default_backend() == "cpu"
+    assert registry.default_interpret() is expect
+    assert registry.resolve_interpret(None) is expect
+    assert registry.resolve_interpret(True) is True
+    assert registry.resolve_interpret(False) is False
+
+
+def test_unknown_backend_raises():
+    xs, c = _make(64, 8)
+    with pytest.raises(ValueError, match="unknown backend"):
+        ops.pairwise_moments(xs, c, backend="bogus")
+    with pytest.raises(ValueError, match="unknown backend"):
+        ops.pairwise_moments_chunked(xs, c, chunk=32, backend="bogus")
+    with pytest.raises(ValueError, match="row tile"):
+        ops.pairwise_moment_sums_rows(xs, c, 0, 8, backend="ref")
+
+
+# Any blocks give bit-identical moments: bi/bj only re-tile the pair space,
+# and bm is accumulated in fixed ACCUM_CHUNK sub-sums. Each case is checked
+# against the blocks the shape rule picks: (8, 24, 512) for the pair tile at
+# 700 x 24, row block 24 for the blocked path, (8, 16, 512) for the row tile.
+@pytest.mark.parametrize(
+    "blocks", [(8, 24, 128), (16, 24, 256), (32, 24, 512), (64, 24, 128)]
+)
+def test_pair_op_parity_bit_identical_across_plans(blocks):
+    xs, c = _make(700, 24)
+    ref1, ref2 = ops.pairwise_moments(
+        xs, c, backend="pallas", interpret=True
+    )
+    m1, m2 = ops.pairwise_moments(
+        xs, c, backend="pallas", interpret=True, blocks=blocks
+    )
+    assert np.array_equal(np.asarray(ref1), np.asarray(m1))
+    assert np.array_equal(np.asarray(ref2), np.asarray(m2))
+
+
+@pytest.mark.parametrize("block", [8, 16])
+def test_blocked_parity_bit_identical_across_blocks(block):
+    import jax
+
+    xs, c = _make(700, 24)
+    ref1, ref2 = ops.pairwise_moments(xs, c, backend="blocked")
+    m1, m2 = jax.jit(ops.pairwise_moments_blocked, static_argnames="block")(
+        xs, c, block=block)
+    assert np.array_equal(np.asarray(ref1), np.asarray(m1))
+    assert np.array_equal(np.asarray(ref2), np.asarray(m2))
+
+
+@pytest.mark.parametrize(
+    "blocks", [(16, 16, 128), (32, 16, 256), (8, 16, 256)]
+)
+def test_rows_op_parity_bit_identical_across_plans(blocks):
+    xs, c = _make(512, 16)
+    r1, r2 = ops.pairwise_moment_sums_rows(
+        xs, c, 0, 16, chunk=512, backend="pallas", interpret=True,
+    )
+    s1, s2 = ops.pairwise_moment_sums_rows(
+        xs, c, 0, 16, chunk=512, backend="pallas", interpret=True,
+        blocks=blocks,
+    )
+    assert np.array_equal(np.asarray(r1), np.asarray(s1))
+    assert np.array_equal(np.asarray(r2), np.asarray(s2))
+
+
+def _pallas_plan(fn, *shapes):
+    """(grid, (bi, bj, bm), (d_pad, m_pad)) of fn's one pallas_call, read
+    from its x_i and x_j blocks."""
+    import jax
+
+    plans = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                x_i, x_j = gm.block_mappings[:2]
+                (bi, bm), (bj, _) = (
+                    tuple(getattr(b, "block_size", b) for b in bmap.block_shape)
+                    for bmap in (x_i, x_j)
+                )
+                plans.append((tuple(gm.grid), (bi, bj, bm),
+                              tuple(x_i.array_aval.shape)))
+            for sub in _subjaxprs(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*[
+        jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes
+    ]).jaxpr)
+    (plan,) = plans
+    return plan
+
+
+# The pair tile at every stage width of the benchmark cells' staged fits:
+# (width, grid, (bi, bj, bm), (d_pad, m_pad)). Rows pad to 8 and columns
+# to 128 above one lane tile; at most 128 columns are one block.
+_GENE964_STAGES = [
+    (964, (121, 8, 2), (8, 128, 2048), (1024, 4096)),
+    (723, (91, 6, 2), (8, 128, 2048), (768, 4096)),
+    (542, (68, 5, 2), (8, 128, 2048), (640, 4096)),
+    (406, (51, 4, 2), (8, 128, 2048), (512, 4096)),
+    (304, (38, 3, 2), (8, 128, 2048), (384, 4096)),
+    (228, (29, 2, 2), (8, 128, 2048), (256, 4096)),
+    (171, (22, 2, 2), (8, 128, 2048), (256, 4096)),
+    (128, (16, 1, 2), (8, 128, 2048), (128, 4096)),
+    (96, (12, 1, 2), (8, 96, 2048), (96, 4096)),
+    (72, (9, 1, 2), (8, 72, 2048), (72, 4096)),
+    (54, (7, 1, 2), (8, 56, 2048), (56, 4096)),
+    (40, (5, 1, 2), (8, 40, 2048), (40, 4096)),
+    (30, (4, 1, 2), (8, 32, 2048), (32, 4096)),
+    (22, (3, 1, 2), (8, 24, 2048), (24, 4096)),
+    (16, (2, 1, 2), (8, 16, 2048), (16, 4096)),
+    (12, (2, 1, 2), (8, 16, 2048), (16, 4096)),
+    (9, (2, 1, 2), (8, 16, 2048), (16, 4096)),
+    (7, (1, 1, 2), (8, 8, 2048), (8, 4096)),
+]
+_STOCKS487_STAGES = [
+    (487, (61, 4, 8), (8, 128, 512), (512, 4096)),
+    (365, (46, 3, 8), (8, 128, 512), (384, 4096)),
+    (274, (35, 3, 8), (8, 128, 512), (384, 4096)),
+    (206, (26, 2, 8), (8, 128, 512), (256, 4096)),
+    (154, (20, 2, 8), (8, 128, 512), (256, 4096)),
+    (116, (15, 1, 8), (8, 120, 512), (120, 4096)),
+    (87, (11, 1, 8), (8, 88, 512), (88, 4096)),
+    (65, (9, 1, 8), (8, 72, 512), (72, 4096)),
+    (49, (7, 1, 8), (8, 56, 512), (56, 4096)),
+    (37, (5, 1, 8), (8, 40, 512), (40, 4096)),
+    (28, (4, 1, 8), (8, 32, 512), (32, 4096)),
+    (21, (3, 1, 8), (8, 24, 512), (24, 4096)),
+    (16, (2, 1, 8), (8, 16, 512), (16, 4096)),
+    (12, (2, 1, 8), (8, 16, 512), (16, 4096)),
+    (9, (2, 1, 8), (8, 16, 512), (16, 4096)),
+    (7, (1, 1, 8), (8, 8, 512), (8, 4096)),
+]
+_CELL_STAGES = {
+    "gene964": (4096, 964, False, _GENE964_STAGES),
+    "stocks487": (3999, 487, False, _STOCKS487_STAGES),
+    "gene964int": (4096, 964, True, _GENE964_STAGES),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_STAGES))
+def test_block_plans_at_cell_stage_widths(cell):
+    """The Pallas grid, blocks and padded extents the shape rules give the
+    benchmark cells' kernels at every stage width of the staged fit."""
+    from repro.core import ordering
+
+    m, d, masked, stages = _CELL_STAGES[cell]
+    widths = [w for w, _ in ordering._stage_schedule(d)]
+    assert widths == [w for w, *_ in stages]
+    for w, *want in stages:
+        if masked:
+            def fn(z, v, c, a, g, n):
+                return ops.pairwise_moments_masked(
+                    z, v, c, a, g, n, backend="pallas", interpret=False)
+            shapes = [(m, w), (m, w)] + [(w, w)] * 4
+        else:
+            def fn(x, c):
+                return ops.pairwise_moments(
+                    x, c, backend="pallas", interpret=False)
+            shapes = [(m, w), (w, w)]
+        assert _pallas_plan(fn, *shapes) == tuple(want), (cell, w)
+
+
+def test_kernel_ops_import_loads_no_obs():
+    """The kernel layer stands below observability: importing it loads no
+    ``repro.obs`` module."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src),
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, repro.kernels.ops; "
+         "print(sorted(m for m in sys.modules if m.startswith('repro.')))"],
+        env=env, capture_output=True, text=True, check=True,
+    ).stdout
+    assert "repro.kernels.ops" in out
+    assert "repro.obs" not in out
+
+
+def test_engine_warmup_compiles():
+    from repro.core import api
+    from repro.obs import compile_log
+    from repro.serve.engine import CausalDiscoveryEngine, FitRequest
+
+    eng = CausalDiscoveryEngine(
+        api.FitConfig(backend="blocked", compaction="staged", min_stage=3)
+    )
+    n0 = compile_log.total("batched.fit_many")
+    assert eng.warmup([(64, 5)]) is None
+    # Warmup pre-compiled the vmap fit program (public compile-log pin:
+    # one event per (shape, config) signature).
+    n_warm = compile_log.total("batched.fit_many")
+    assert n_warm == n0 + 1
+    x = RNG.laplace(size=(64, 5)).astype(np.float32)
+    (req,) = eng.run([FitRequest(data=x)])
+    assert sorted(req.result.order.tolist()) == list(range(5))
+    # Steady state: the warmed shape serves with zero new compiles.
+    assert compile_log.total("batched.fit_many") == n_warm

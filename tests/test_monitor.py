@@ -451,7 +451,7 @@ def test_score_chunks_many_matches_sequential():
 
 def test_batched_scorer_compiles_once_per_shape():
     """Repeat batched scoring over the same (d, lags) family replays
-    one compiled program (padding to the dispatcher bucket)."""
+    one compiled program (padding to the micro-batch bucket)."""
     mons, states = [], []
     for seed in (3, 4):
         m, cs = _armed_monitor_and_chunks(seed)

@@ -360,6 +360,101 @@ def test_rolling_validates_inputs():
         roll.push(np.zeros((16, 4), np.float32))
 
 
+def test_rolling_window_moment_chunk_defaults_to_stream_chunk():
+    r = window.RollingVarLiNGAM(d=4, chunk=64, window_chunks=3)
+    assert r.config.moment_chunk == 64
+
+
+def _window_moment_chunks(backend, monkeypatch):
+    return [
+        window.RollingVarLiNGAM(
+            d=4, chunk=c, window_chunks=3,
+            config=api.FitConfig(backend=backend, compaction="staged"),
+        ).config.moment_chunk
+        for c in (64, 100, 200, 700)
+    ]
+
+
+def _recorded_slabs(run, monkeypatch):
+    from repro.infer import rca
+
+    slabs = []
+    pad_rows = rca._pad_rows
+
+    def record(block, slab, axis=0):
+        slabs.append(slab)
+        return pad_rows(block, slab, axis)
+
+    monkeypatch.setattr(rca, "_pad_rows", record)
+    for n in (1, 100, 513):
+        run(n)
+    return slabs
+
+
+def _rca_slabs(backend, monkeypatch):
+    from repro.infer import rca
+
+    d = 5
+    graph = api.FitResult(
+        order=jnp.arange(d), adjacency=jnp.zeros((d, d), jnp.float32),
+        resid_var=jnp.ones((d,), jnp.float32),
+    )
+    return _recorded_slabs(
+        lambda n: rca.attribute(graph, np.ones((n, d), np.float32)),
+        monkeypatch,
+    )
+
+
+def _query_rca_slabs(backend, monkeypatch):
+    from repro.infer import query
+
+    d = 5
+    graph = api.FitResult(
+        order=jnp.arange(d), adjacency=jnp.zeros((d, d), jnp.float32),
+        resid_var=jnp.ones((d,), jnp.float32),
+    )
+    engine = query.QueryEngine(batch_size=2)
+    return _recorded_slabs(
+        lambda n: engine.run([query.RCAQuery(
+            graph=graph, rows=np.ones((n, d), np.float32))]),
+        monkeypatch,
+    )
+
+
+def _monitor_buckets(backend, monkeypatch):
+    from repro.kernels.tune import registry
+    from repro.stream import monitor
+
+    monkeypatch.setattr(registry, "default_backend", lambda: backend)
+    return [monitor._batch_bucket(n)
+            for n in (1, 3, 5, 100, 128, 129, 300, 1000)]
+
+
+# Sample slabs and batch buckets that four callers outside the kernels
+# size from the shapes: the values each had while a kernel block
+# dispatcher chose them (per backend where they differ). The RCA slab
+# (attribute and the query engine) is recorded once per slab pass over
+# 1, 100 and 513 rows.
+_SLAB_DECISIONS = {
+    "window": (_window_moment_chunks, {
+        "blocked": [64, 100, 200, 700], "pallas": [64, 100, 200, 700]}),
+    "rca": (_rca_slabs, {
+        "blocked": [512, 512, 512, 512], "pallas": [512, 512, 512, 512]}),
+    "query": (_query_rca_slabs, {
+        "blocked": [512, 512, 512, 512], "pallas": [512, 512, 512, 512]}),
+    "monitor": (_monitor_buckets, {
+        "blocked": [1, 3, 5, 100, 128, 129, 300, 1000],
+        "pallas": [1, 4, 8, 128, 128, 256, 384, 1024]}),
+}
+
+
+@pytest.mark.parametrize("caller", sorted(_SLAB_DECISIONS))
+def test_slab_decisions_match_parent(caller, monkeypatch):
+    decide, want = _SLAB_DECISIONS[caller]
+    for backend in ("blocked", "pallas"):
+        assert decide(backend, monkeypatch) == want[backend], backend
+
+
 # ----------------------------------------------------------------------
 # Sessions + engine batching
 # ----------------------------------------------------------------------

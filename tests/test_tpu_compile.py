@@ -51,8 +51,8 @@ def _sds(sharding, *shape):
 _PALLAS = dict(backend="pallas", interpret=False)
 
 # (case, kernel call, argument shapes): the widths the main path runs.
-# Each compiles with the dispatcher's heuristic plan; a plan whose working
-# set overflows the chip's scoped VMEM fails here (RESOURCE_EXHAUSTED).
+# Each compiles with the blocks of the shape rules; blocks whose working
+# set overflow the chip's scoped VMEM fail here (RESOURCE_EXHAUSTED).
 _KERNELS = {
     # gene-964: d_pad 1024, m_pad 65,536, blocks (8, 128, 2048).
     "pair_gene964": (
@@ -137,10 +137,9 @@ def test_masked_kernel_plan_counts_its_mask_blocks_within_vmem():
     """The masked kernel's heuristic blocks at 4,096 x 964, with its two
     validity blocks and two more per-pair blocks counted, fit the VMEM
     budget that the compile above holds them to."""
-    plan = registry.dispatch_heuristic(
-        "pairwise_moments_masked", (4096, 964), backend="pallas")
-    masked = registry.vmem_bytes(plan.bi, plan.bj, plan.bm, masked=True)
-    assert registry.vmem_bytes(plan.bi, plan.bj, plan.bm) < masked
+    blocks = registry.heuristic_pair_blocks(964, 4096)
+    masked = registry.vmem_bytes(*blocks, masked=True)
+    assert registry.vmem_bytes(*blocks) < masked
     assert masked <= registry._VMEM_BUDGET
 
 
