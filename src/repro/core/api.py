@@ -228,13 +228,11 @@ def finish_fit(x, order, config: FitConfig, valid=None) -> FitResult:
     """Adjacency + residual diagnostics given the causal order.
 
     Shared tail of every plan: the mesh plan runs the sharded ordering
-    and then this exact computation with its OLS row solves tiled over
-    the pair axis via ``pruning.ols_rows`` — identical per-row
-    arithmetic, so the plans' coefficients agree to the ulp-level
-    lowering differences of batched solves (exactly, at the parity
-    cells the tests pin). With ``valid`` (interventional data), each
-    row's regression and residual variance run over the samples valid
-    for its variable.
+    and then this exact computation, the OLS factor
+    (``pruning.ols_from_cov``) replicated on every device, so the plans'
+    coefficients are bit-identical at the parity cells the tests pin.
+    With ``valid`` (interventional data), each row's regression and
+    residual variance run over the samples valid for its variable.
     """
     with jax.named_scope("lingam.prune"):
         b = pruning.estimate_adjacency(
@@ -392,6 +390,7 @@ def fit_impl_from_stats(x, mean, cov, config: FitConfig) -> FitResult:
         b = pruning.estimate_adjacency_from_cov(
             cov,
             order,
+            x.shape[0],
             method=config.prune_method,
             threshold=config.prune_threshold,
             **config.prune_kwargs_dict,

@@ -2,16 +2,19 @@
 
 After DirectLiNGAM establishes the order k(.), the connection strengths are
 estimated by regressing each variable on its predecessors. The paper leaves
-this on CPU (numpy/sklearn, ~4% of runtime); here it is vectorized as a
-masked *batched* OLS (one vmapped linear solve per variable) plus an
-optional adaptive-lasso refinement (FISTA on the weighted-L1 problem, the
-jax-native equivalent of lingam's LassoLarsIC step).
+this on CPU (numpy/sklearn, ~4% of runtime); here every variable's OLS
+comes from one Cholesky factor of the covariance taken in causal order
+(:func:`ols_from_cov`), plus an optional adaptive-lasso refinement (FISTA
+on the weighted-L1 problem, the jax-native equivalent of lingam's
+LassoLarsIC step). Interventional data, where each variable has its own
+samples, solves one masked system per variable
+(:func:`masked_ols_adjacency`).
 
-The per-variable solves are row-independent given the (replicated)
-covariance, so the mesh execution plan (:mod:`repro.core.sharded`) calls
-the row-tile entry points (:func:`ols_rows`, :func:`lasso_rows`) on its
-pair-axis tile and ``all_gather``s the rows — bit-identical to the
-single-device solve because each row's computation is unchanged.
+The lasso's per-variable solves are row-independent given the
+(replicated) correlation, so the fully sharded mesh finish
+(:mod:`repro.core.sharded`) calls :func:`lasso_rows` on its pair-axis
+tile and ``all_gather``s the rows; the OLS factor is milliseconds and
+runs replicated on every device.
 """
 
 from __future__ import annotations
@@ -29,6 +32,15 @@ EPS = 1e-9
 #: d = 964); rows are solved in batches of at most this many floats
 #: instead — one batch, i.e. a plain vmap, whenever d^3 fits.
 _SOLVE_BATCH_FLOATS = 1 << 24
+
+#: Share of its variance added to the diagonal of each variable the
+#: factor in :func:`ols_from_cov` cannot resolve in fp32 (see there).
+_RANK_RIDGE = 2.0 ** -10
+
+#: Two columns with ``corr^2 >= 1 - _TWIN_GAP`` count as collinear: the
+#: later one's pivot, at most ``_TWIN_GAP`` of its variance, is then
+#: within reach of the factor's fp32 rounding.
+_TWIN_GAP = 2.0 ** -16
 
 
 def _map_rows(fn, *rows):
@@ -48,49 +60,60 @@ def pred_mask(order):
 _pred_mask = pred_mask  # backwards-compatible private alias
 
 
-def ols_rows(cov, mask_rows, cov_rows):
-    """Masked OLS solves for a tile of variables.
+def ols_from_cov(cov, order, n_rows: int):
+    """OLS of every variable on its causal predecessors, from the (ddof=0)
+    covariance of ``n_rows`` samples.
 
-    Args:
-      cov:       (d, d) covariance of the centered data (replicated).
-      mask_rows: (tile, d) predecessor masks for the tile's variables.
-      cov_rows:  (tile, d) the same variables' covariance rows.
-    Returns:
-      (tile, d) coefficient rows. Rows whose mask is all-False (e.g.
-      mesh padding rows) solve an identity system and come back zero.
-    """
+    With ``S`` the covariance taken in causal order (``cov[order][:,
+    order]``, ``EPS`` on its diagonal as every row's system has) and
+    ``S = C C^T`` its Cholesky factor, S's leading k x k block is the
+    system of the k-th variable in the order and C's leading block
+    factors it, so one factor holds every row's regression:
+    ``B_ord = I - diag(C) C^-1``, strictly lower triangular, scattered
+    back out of the order. The triangular solve runs its products at
+    ``HIGHEST`` precision.
 
-    def solve_one(mask_i, cov_xi):
-        mm = mask_i[:, None] & mask_i[None, :]
-        a = jnp.where(mm, cov, 0.0) + jnp.diag(jnp.where(mask_i, EPS, 1.0))
-        b = jnp.where(mask_i, cov_xi, 0.0)
-        return jnp.linalg.solve(a, b)
-
-    return _map_rows(solve_one, mask_rows, cov_rows)
-
-
-def ols_from_cov(cov, order):
-    """Masked OLS adjacency from a precomputed (ddof=0) covariance.
+    **Rank.** Where S is singular the fp32 factor breaks down (a pivot
+    that rounding leaves negative is NaN) and OLS has no unique answer.
+    The rule: every variable gets ``_RANK_RIDGE`` (2^-10) of its
+    variance on S's diagonal when ``n_rows <= d`` (the covariance has
+    rank below d); otherwise only a variable whose column is collinear
+    with another's (``corr^2 >= 1 - _TWIN_GAP``, a duplicated column)
+    gets it. Rows that see such a variable come back as the finite ridge
+    estimates; every other variable's diagonal carries ``EPS`` alone.
+    Collinearity among three or more columns at ``n_rows > d`` is not
+    detected and can still give NaN rows.
 
     The data-free tail of :func:`ols_adjacency`: given the centered
     covariance — from raw data, or merged incrementally by the streaming
-    moment store — the per-variable solves need no further data pass.
+    moment store — no further data pass is needed.
     """
-    mask = pred_mask(order)  # (d, d)
-    return ols_rows(cov, mask, cov)
+    d = cov.shape[0]
+    s = cov[order][:, order]
+    var = jnp.diagonal(s)
+    corr2 = s * s / jnp.maximum(var[:, None] * var[None, :], 1e-30)
+    twin = jnp.any(
+        (corr2 >= 1.0 - _TWIN_GAP) & ~jnp.eye(d, dtype=bool), axis=1)
+    ridge = jnp.where(twin | (n_rows <= d), _RANK_RIDGE * var, 0.0)
+    c = jnp.linalg.cholesky(s + jnp.diag(EPS + ridge))
+    with jax.default_matmul_precision("highest"):
+        c_inv = jax.scipy.linalg.solve_triangular(
+            c, jnp.eye(d, dtype=s.dtype), lower=True)
+    b_ord = jnp.tril(-jnp.diagonal(c)[:, None] * c_inv, -1)
+    pos = jnp.argsort(order)
+    return b_ord[pos][:, pos]
 
 
 @functools.partial(jax.jit, static_argnames=())
 def ols_adjacency(x, order):
-    """Batched masked OLS: B[i, j] = coefficient of x_j in the regression of
-    x_i on its causal predecessors. Rows/cols outside the predecessor set are
-    pinned via an identity-augmented system so one vmapped solve handles all
-    variables with static shapes.
+    """OLS adjacency: B[i, j] = coefficient of x_j in the regression of
+    x_i on its causal predecessors (:func:`ols_from_cov` of the data's
+    covariance).
     """
     m, d = x.shape
     xc = x - jnp.mean(x, axis=0, keepdims=True)
     cov = (xc.T @ xc) / m  # (d, d)
-    return ols_from_cov(cov, order)
+    return ols_from_cov(cov, order, m)
 
 
 @jax.jit
@@ -103,8 +126,8 @@ def masked_ols_adjacency(x, order, valid):
     masked system of its own covariance over ``S_j``, ``xc^T diag(V_j) xc
     / n_j - mu_j mu_j^T`` (``xc`` centered once over all samples, which
     moves no covariance and keeps the one-pass form well conditioned), at
-    full fp32 matmul precision. Rows are solved in the same
-    memory-bounded batches as :func:`ols_rows`.
+    full fp32 matmul precision. Rows are solved in memory-bounded
+    batches (:func:`_map_rows`).
     """
     x = x.astype(jnp.float32)
     xc = x - jnp.mean(x, axis=0, keepdims=True)
@@ -192,8 +215,9 @@ def adaptive_lasso_adjacency(x, order, lam=0.01, gamma=1.0, n_steps=400):
     return b_std * (sd[:, None] / sd[None, :])
 
 
-@functools.partial(jax.jit, static_argnames=("n_steps",))
-def adaptive_lasso_from_cov(cov, order, lam=0.01, gamma=1.0, n_steps=400):
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_steps"))
+def adaptive_lasso_from_cov(cov, order, n_rows, lam=0.01, gamma=1.0,
+                            n_steps=400):
     """Adaptive lasso from a precomputed (ddof=0) covariance.
 
     Same estimator as :func:`adaptive_lasso_adjacency` with the
@@ -207,7 +231,7 @@ def adaptive_lasso_from_cov(cov, order, lam=0.01, gamma=1.0, n_steps=400):
     sd = jnp.maximum(jnp.sqrt(jnp.maximum(jnp.diagonal(cov), 0.0)), 1e-12)
     corr = cov / (sd[:, None] * sd[None, :])
     mask = pred_mask(order)
-    b_ols = ols_from_cov(cov, order) * (sd[None, :] / sd[:, None])
+    b_ols = ols_from_cov(cov, order, n_rows) * (sd[None, :] / sd[:, None])
     w = 1.0 / jnp.maximum(jnp.abs(b_ols), 1e-3) ** gamma
     lip = jnp.float32(d)
     b_std = lasso_rows(corr, mask, corr, w, lam, lip, n_steps)
@@ -244,7 +268,8 @@ def estimate_adjacency(
 
 
 def estimate_adjacency_from_cov(
-    cov, order, method: str = "ols", threshold: float = 0.0, **kw
+    cov, order, n_rows: int, method: str = "ols", threshold: float = 0.0,
+    **kw
 ):
     """:func:`estimate_adjacency` from precomputed moments (no data pass).
 
@@ -254,9 +279,9 @@ def estimate_adjacency_from_cov(
     matmul entirely.
     """
     if method == "ols":
-        b = ols_from_cov(cov, order)
+        b = ols_from_cov(cov, order, n_rows)
     elif method == "adaptive_lasso":
-        b = adaptive_lasso_from_cov(cov, order, **kw)
+        b = adaptive_lasso_from_cov(cov, order, n_rows, **kw)
     else:
         raise ValueError(f"unknown method: {method}")
     return apply_threshold(b, threshold)

@@ -29,9 +29,9 @@ O(d^2) arithmetic.
 :func:`fit_sharded` compiles the *full* fit — ordering (with in-trace
 staged compaction when configured: stage widths stay multiples of the
 pair-axis size, every shard gathers the same surviving columns) followed
-by adjacency/pruning with the per-variable solves tiled over the pair
-axis, and residual diagnostics — as one ``shard_map`` program returning
-the same :class:`~repro.core.api.FitResult` pytree as the local plan.
+by adjacency/pruning (the OLS factor replicated) and residual
+diagnostics — as one ``shard_map`` program returning the same
+:class:`~repro.core.api.FitResult` pytree as the local plan.
 The finish has two modes (``Partition.gather_finish``): the default
 reassembles the data per device and reduces the covariance in a fixed
 replicated order — bit-identical leaves at the parity cells
@@ -211,30 +211,24 @@ def _pair_row_tiles(reducer: MeshReducer, order, d: int):
 
 def _finish_sharded(x, order, config: FitConfig, reducer: MeshReducer):
     """Bit-exact finish (``gather_finish=True``): adjacency +
-    diagnostics on the reassembled data, row solves tiled over the pair
-    axis.
+    diagnostics on the reassembled data, replicated.
 
-    Mirrors :func:`repro.core.api.finish_fit` computation-for-computation:
-    the covariance is reduced replicated (fixed reduction order) and
-    each variable's masked OLS solve — row-independent given that
-    covariance — runs on the device owning its row tile via the shared
-    ``pruning.ols_rows``. The adaptive-lasso refinement runs replicated
-    through the shared ``pruning`` entry point instead: its FISTA
-    iterations are batched matvecs whose reduction lowering depends on
-    the batch size, so a row tile would drift from the local plan by
-    ulps over the 400 iterations — and it is part of the ~4% tail
-    anyway. (Batched ``linalg.solve`` lowering can also differ by batch
-    size at some shapes; the parity tests pin the cells where the OLS
-    tiles are exact, and elsewhere the tiles agree to ulps.)
+    Mirrors :func:`repro.core.api.finish_fit` computation-for-computation
+    through the shared ``pruning`` entry points: the covariance is
+    reduced replicated (fixed reduction order) and every device runs the
+    OLS factor (``pruning.ols_from_cov``, milliseconds, so a tile of it
+    would buy nothing) and the adaptive-lasso refinement on it — the same
+    code on the same covariance as the local plan. The lasso is not
+    tiled here: its FISTA matvecs lower differently by batch size, so a
+    row tile would drift from the local plan by ulps over 400 iterations.
     """
     m, d = x.shape
-    mask_rows, rows_of, gather = _pair_row_tiles(reducer, order, d)
 
     with jax.named_scope("lingam.prune"):
         if config.prune_method == "ols":
             xc = x - jnp.mean(x, axis=0, keepdims=True)
             cov = (xc.T @ xc) / m
-            b = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
+            b = pruning.ols_from_cov(cov, order, m)
         elif config.prune_method == "adaptive_lasso":
             b = pruning.adaptive_lasso_adjacency(
                 x, order, **config.prune_kwargs_dict
@@ -254,13 +248,13 @@ def _finish_sharded_scaled(
 ):
     """Fully sharded finish (``gather_finish=False``): the dataset is
     never reassembled — the covariance/correlation are psum-reduced over
-    sample shards, solves run on pair-axis row tiles, and the residual
-    diagnostics stay on local rows. Per-device memory is
+    sample shards, the OLS factor runs replicated, the lasso's row
+    solves run on pair-axis row tiles, and the residual diagnostics stay
+    on local rows. Per-device memory is
     O(m_local * d + d^2), the scale regime the ordering already runs in;
     coefficients agree with the gathered finish to fp32 reduction order.
     """
     x = x_local[:, :d]
-    mask_rows, rows_of, gather = _pair_row_tiles(reducer, order, d)
 
     with jax.named_scope("lingam.prune"):
         mu = reducer.mean_over_samples(x)
@@ -268,7 +262,7 @@ def _finish_sharded_scaled(
         cov = reducer.gram_mean(xc)
 
         if config.prune_method == "ols":
-            b = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
+            b = pruning.ols_from_cov(cov, order, reducer.m)
         elif config.prune_method == "adaptive_lasso":
             kw = config.prune_kwargs_dict
             lam = kw.get("lam", 0.01)
@@ -277,10 +271,11 @@ def _finish_sharded_scaled(
             var = reducer.mean_over_samples(xc * xc)
             sd = jnp.maximum(jnp.sqrt(var), 1e-12)
             corr = reducer.gram_mean(xc / sd[None, :])
-            b_ols = gather(pruning.ols_rows(cov, mask_rows, rows_of(cov)))
+            b_ols = pruning.ols_from_cov(cov, order, reducer.m)
             b_ols_std = b_ols * (sd[None, :] / sd[:, None])
             w = 1.0 / jnp.maximum(jnp.abs(b_ols_std), 1e-3) ** gamma
             lip = jnp.float32(d)
+            mask_rows, rows_of, gather = _pair_row_tiles(reducer, order, d)
             b_std = gather(pruning.lasso_rows(
                 corr, mask_rows, rows_of(corr), rows_of(w), lam, lip, n_steps
             ))

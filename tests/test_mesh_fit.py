@@ -211,12 +211,41 @@ _ROUTING_SCRIPT = textwrap.dedent(
 )
 
 
-def _run_subprocess(script, timeout=1200):
+_PRUNE_SCRIPT = textwrap.dedent(
+    """
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import numpy as np
+    import jax.numpy as jnp
+    from repro.core import api
+    from repro.data.simulate import simulate_lingam
+
+    # Both plans run pruning.ols_from_cov on the same replicated
+    # covariance (the lasso on top of it), so the leaves are bit-equal
+    # for either pruner, padded pair axis included (d=23 over 4).
+    kw = dict(prune_method=sys.argv[1])
+    if kw["prune_method"] == "adaptive_lasso":
+        kw.update(prune_kwargs=dict(lam=0.05), prune_threshold=0.02)
+    x = jnp.asarray(simulate_lingam(m=300, d=23, seed=5).data)
+    ref = api.fit_fn(x, api.FitConfig(compaction="staged", **kw))
+    for shape in ((("data", 2), ("model", 2)), (("data", 1), ("model", 4))):
+        got = api.fit_fn(x, api.FitConfig(
+            compaction="staged", partition=api.Partition(mesh=shape, chunk=32),
+            **kw))
+        for leaf in ("order", "adjacency", "resid_var"):
+            assert np.array_equal(np.asarray(getattr(ref, leaf)),
+                                  np.asarray(getattr(got, leaf))), (shape, leaf)
+    print("MESH_PRUNE_OK")
+    """
+)
+
+
+def _run_subprocess(script, timeout=1200, args=()):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)
     return subprocess.run(
-        [sys.executable, "-c", script],
+        [sys.executable, "-c", script, *args],
         capture_output=True,
         text=True,
         env=env,
@@ -241,3 +270,13 @@ def test_mesh_routing_engine_and_varlingam():
     out = _run_subprocess(_ROUTING_SCRIPT)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "MESH_ROUTING_OK" in out.stdout
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("prune_method", ["ols", "adaptive_lasso"])
+def test_mesh_prune_bit_identical(prune_method):
+    """The mesh finish prunes with the local plan's code: bit-identical
+    leaves for OLS and for the adaptive lasso."""
+    out = _run_subprocess(_PRUNE_SCRIPT, args=(prune_method,))
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "MESH_PRUNE_OK" in out.stdout
